@@ -95,6 +95,7 @@ import subprocess
 import sys
 
 from benchmarks.common import RESULTS_DIR
+from repro.compile_cache import enable_compile_cache
 
 # timing leaves: key -> True when larger-is-better (throughput)
 _TIME_KEYS = {"warm_us": False, "ttft_ms": False, "decode_tok_s": True,
@@ -366,4 +367,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

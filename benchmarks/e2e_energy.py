@@ -30,6 +30,7 @@ from repro.configs import get_config, list_configs
 from repro.core import costs, dse
 from repro.serving.engine import energy_report
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 # the one smoke configuration: shared by the --smoke CLI (which refreshes
 # the committed e2e_energy_smoke.json) and benchmarks/compare.py's fresh
@@ -168,6 +169,7 @@ def run_pareto(archs=None, n_cols=1 << 11, prefill_bucket=128,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny Monte-Carlo + separate record for the CI "

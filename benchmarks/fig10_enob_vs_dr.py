@@ -14,6 +14,7 @@ from repro.core import distributions as D
 from repro.core import energy as E
 from repro.core import formats as F
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 
 def run():
@@ -52,4 +53,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

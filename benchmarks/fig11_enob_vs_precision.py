@@ -13,6 +13,7 @@ from repro.core import adc as A
 from repro.core import distributions as D
 from repro.core import formats as F
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 
 def run():
@@ -45,4 +46,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -13,6 +13,7 @@ from repro.core import dse as S
 from repro.core import energy as E
 from repro.core import formats as F
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 ENERGY_LIMIT_FJ = 100.0
 
@@ -108,4 +109,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

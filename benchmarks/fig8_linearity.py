@@ -14,6 +14,7 @@ import numpy as np
 from repro.core import formats as F
 from repro.core import mac as M
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 FMT = F.FP6_E2M3   # the paper's implemented configuration
 
@@ -73,4 +74,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -9,6 +9,7 @@ import jax
 from repro.core import distributions as D
 from repro.core import formats as F
 from benchmarks.common import emit, save_json, time_call
+from repro.compile_cache import enable_compile_cache
 
 
 def core_sqnr(key, fmt, eps=0.01, k=50.0, n=1 << 20):
@@ -50,4 +51,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -40,6 +40,7 @@ from repro.training.fault import FaultPlan, make_fault_plan
 from repro.training.supervisor import DrillConfig, Supervisor, price_drill
 from repro.training.trainer import TrainConfig
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 # the same two cache-state extremes the traffic bench sweeps: attention
 # (KV growth) and SSM (fixed recurrent state)
@@ -128,6 +129,7 @@ def run(steps=16, batch=4, seq_len=32, local_every=2, durable_every=6,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)

@@ -44,6 +44,7 @@ import numpy as np
 from repro.core.formats import FP4_E2M1, FP6_E3M2, quantize
 from repro.kernels.dispatch import grmac_matmul
 from benchmarks.common import emit, save_json, time_call
+from repro.compile_cache import enable_compile_cache
 
 _DEFAULT_BACKENDS = ("xla", "tiled", "ref", "pallas_interpret")
 _GRANS = ["conv", "row", "unit"]
@@ -173,6 +174,7 @@ def run(backends=None, shapes=None, smoke=False, n_iter=5, record=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--backend", default="all",
                     help="'all' or comma list of dispatch backends "

@@ -7,6 +7,7 @@ from repro.core import distributions as D
 from repro.core import formats as F
 from repro.core import mac as M
 from benchmarks.common import emit, save_json, time_call
+from repro.compile_cache import enable_compile_cache
 
 
 def run():
@@ -48,4 +49,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     from benchmarks import (
@@ -87,4 +89,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,7 @@ from repro.core import costs
 from repro.models import init_params
 from repro.serving.engine import Engine, ServeConfig
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 ARCHS = [
     ("attn", "qwen2-1.5b"),
@@ -123,6 +124,7 @@ def run(prompt_len=64, steps=32, slots=4, ctx=256, archs=None,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--steps", type=int, default=32)

@@ -45,6 +45,7 @@ from repro.serving.params import SamplingParams
 from repro.serving.speculative import (SpecConfig, SpecDecoder,
                                        draft_arch_for, price_speculation)
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 ARCHS = [
     ("attn", "qwen2-1.5b"),
@@ -184,4 +185,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -73,6 +73,7 @@ from repro.serving.scheduler import (
     synth_traffic,
 )
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 
 # attention KV and SSM recurrent-state cache families: the two extremes
 # of per-slot state the scheduler juggles (serve_bench covers all four
@@ -411,6 +412,7 @@ def run_shared_prefix(n_requests=32, slots=4, ctx=256, n_prefixes=4,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--slots", type=int, default=4)

@@ -15,8 +15,7 @@ three machine-checked proofs, one module per pass:
     ``cim_<site>_m<M>_k<K>_n<N>`` marker whose non-transpose
     ``cim_values`` count matches the CostLedger entry exactly, or
     declared digital via a ``dig_*`` scope. Reports untagged MACs with
-    source locations, count mismatches, and f32 promotions inside the
-    ``REPRO_GRMAC_BF16_VALUES`` regime.
+    source locations and count mismatches.
 
 ``invariants``
     ``InstrumentedEngine`` wraps the serving engine's dedicated seams
@@ -37,9 +36,8 @@ Report schema (``python -m repro.analysis --out ...``)::
          dot_generals, convs, tagged_values, tagged_gains,
          declared_digital, transposes, untagged, untagged_details[],
          ledger_mismatches, ledger_mismatch_details[], dtype_f32,
-         dtype_bf16, dtype_flags[], calls, macs,
-         contracts: {"<site>_m<M>_k<K>_n<N>": {ledger, traced}}}},
-         "bf16_regime": {... decode re-audit under bf16 values ...}}},
+         dtype_bf16, calls, macs,
+         contracts: {"<site>_m<M>_k<K>_n<N>": {ledger, traced}}}}}},
      "invariants": {"violations": N, "configs": {"<name>": {
          traces{}, compiles, fetches, steps}}}}
 

@@ -22,7 +22,7 @@ anything else
     an **untagged MAC** — a ledger leak, reported with the primitive's
     user source location.
 
-Name-stack semantics (verified on jax 0.4.37): sub-jaxpr bodies reset
+Name-stack semantics (verified on jax 0.9.0): sub-jaxpr bodies reset
 ``eqn.source_info.name_stack``, but the call eqn carries the enclosing
 scopes, so the walker prefix-accumulates stacks when it recurses. Under
 ``grad``, transposed applications carry ``transpose(...)`` in the stack
@@ -31,10 +31,7 @@ contracts only; the STE backward is explicitly digital).
 
 The audit forces a deterministic kernel regime during tracing
 (``REPRO_GRMAC_BACKEND=xla`` so no ``pallas_call`` hides its dots,
-sanitize/bf16 off); ``bf16_values_regime=True`` re-traces under
-``REPRO_GRMAC_BF16_VALUES=1`` and flags any f32 values contraction at a
-site whose formats admit exact bf16 products (an unexpected dtype
-promotion in the fast-GEMM regime).
+sanitizer off).
 """
 from __future__ import annotations
 
@@ -98,24 +95,20 @@ def iter_eqns(jaxpr, prefix: str = ""):
 
 
 def _source_of(eqn) -> Tuple[Optional[str], Optional[int]]:
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return frame.file_name, int(frame.start_line)
-    except Exception:
-        pass
-    return None, None
+    """The innermost user (non-JAX) frame that staged ``eqn``."""
+    from jax._src import source_info_util  # no public equivalent
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return None, None
+    return frame.file_name, int(frame.start_line)
 
 
 @contextlib.contextmanager
-def _audit_env(bf16: bool):
+def _audit_env():
     """Pin the kernel regime for a trace: concrete xla backend (Pallas
-    hides its dots inside ``pallas_call``), sanitizer off (no
-    ``debug_callback`` noise in the golden), bf16 values as requested."""
-    keys = {"REPRO_GRMAC_BACKEND": "xla",
-            "REPRO_SANITIZE": "0",
-            "REPRO_GRMAC_BF16_VALUES": "1" if bf16 else "0"}
+    hides its dots inside ``pallas_call``) and sanitizer off (no
+    ``debug_callback`` noise in the golden)."""
+    keys = {"REPRO_GRMAC_BACKEND": "xla", "REPRO_SANITIZE": "0"}
     saved = {k: os.environ.get(k) for k in keys}
     os.environ.update(keys)
     try:
@@ -142,17 +135,7 @@ def _ledger_contracts(ledger: costs.CostLedger) -> Dict[str, dict]:
     return out
 
 
-def _bf16_exact(fmt_x_name: str, fmt_w_name: str) -> bool:
-    from repro.core import formats
-    from repro.kernels.xla import bf16_products_exact
-    fx = getattr(formats, fmt_x_name, None)
-    fw = getattr(formats, fmt_w_name, None)
-    return (fx is not None and fw is not None
-            and bf16_products_exact(fx, fw))
-
-
-def audit_phase(arch, phase: str, *,
-                bf16_values_regime: bool = False) -> dict:
+def audit_phase(arch, phase: str) -> dict:
     """Audit one (arch, phase): trace, walk, classify, cross-check.
 
     Returns a JSON-able dict; ``untagged == 0`` and
@@ -160,7 +143,7 @@ def audit_phase(arch, phase: str, *,
     """
     fn, args = costs.phase_trace_spec(arch, phase)
     ledger = costs.CostLedger()
-    with _audit_env(bf16_values_regime):
+    with _audit_env():
         with costs.recording(ledger):
             closed = jax.make_jaxpr(fn)(*args)
 
@@ -174,7 +157,6 @@ def audit_phase(arch, phase: str, *,
     dtype_f32 = dtype_bf16 = 0
     untagged: List[dict] = []
     unknown_sites: List[dict] = []
-    dtype_flags: List[dict] = []
     opaque: List[dict] = []
 
     for eqn, stack in iter_eqns(closed.jaxpr):
@@ -219,14 +201,6 @@ def audit_phase(arch, phase: str, *,
                     dtype_bf16 += 1
                 else:
                     dtype_f32 += 1
-                    if (bf16_values_regime and rec["mode"] == "grmac"
-                            and _bf16_exact(rec["fmt_x"], rec["fmt_w"])):
-                        fname, line = _source_of(eqn)
-                        dtype_flags.append(
-                            {"marker": marker.group(0), "dtype": str(op),
-                             "file": fname, "line": line,
-                             "reason": "f32 values contraction in the "
-                                       "bf16-values regime"})
             elif "cim_gains" in stack:
                 tagged_gains += 1
             else:
@@ -266,7 +240,6 @@ def audit_phase(arch, phase: str, *,
         "ledger_mismatch_details": mismatches,
         "dtype_f32": dtype_f32,
         "dtype_bf16": dtype_bf16,
-        "dtype_flags": dtype_flags,
         "calls": sum(r["ledger"] for r in contracts.values()),
         "macs": ledger.macs(),
         "contracts": {
@@ -276,27 +249,9 @@ def audit_phase(arch, phase: str, *,
     }
 
 
-def _runs_grmac(arch) -> bool:
-    if not arch.cim.enabled:
-        return False
-    designs = [arch.cim.for_site(s) for s in SITES]
-    return any(d is not None and d.enabled and d.mode == "grmac"
-               for d in designs)
-
-
-def audit_arch(arch, phases=("prefill", "decode", "train"), *,
-               bf16_regime_check: bool = True) -> dict:
-    """Audit every phase of one arch. When the arch runs grmac anywhere
-    and ``bf16_regime_check`` is set, the decode phase is additionally
-    re-audited under ``REPRO_GRMAC_BF16_VALUES=1`` to catch f32 dtype
-    promotions inside the bf16 values path."""
+def audit_arch(arch, phases=("prefill", "decode", "train")) -> dict:
+    """Audit every phase of one arch."""
     out = {"phases": {p: audit_phase(arch, p) for p in phases}}
-    if bf16_regime_check and _runs_grmac(arch) and "decode" in phases:
-        out["bf16_regime"] = audit_phase(arch, "decode",
-                                         bf16_values_regime=True)
-    checked = list(out["phases"].values())
-    if "bf16_regime" in out:
-        checked.append(out["bf16_regime"])
     out["failures"] = sum(ph["untagged"] + ph["ledger_mismatches"]
-                          + len(ph["dtype_flags"]) for ph in checked)
+                          for ph in out["phases"].values())
     return out

@@ -1,78 +1,29 @@
-"""Version-divergent JAX APIs, resolved in one place.
-
-The repo pins no exact JAX version: CI and the paper experiments run the
-0.4.x LTS line while TPU pods track current releases. Every API whose
-name or signature moved between those lines is wrapped here so the rest
-of the codebase imports `repro.compat` instead of branching inline.
-
-Shimmed surfaces
-----------------
-``pallas_tpu_compiler_params(...)``
-    ``jax.experimental.pallas.tpu`` renamed ``TPUCompilerParams`` (<= 0.4.x /
-    early 0.5.x) to ``CompilerParams`` (newer). Returns an instance of
-    whichever class exists, or ``None`` when neither does (pure-interpret
-    environments) so callers can omit the kwarg.
+"""JAX API spellings shared across the codebase, for the pinned JAX 0.9.0.
 
 ``make_mesh(axis_shapes, axis_names)``
-    ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-    ``jax.make_mesh``) only exist on newer JAX. On those versions we pass
-    explicit ``Auto`` axis types (the repo never uses ``Explicit``
-    sharding); on older versions the kwarg is dropped — ``Auto`` is the
-    only behaviour 0.4.x has, so the semantics are identical.
+    ``jax.make_mesh`` with every axis typed ``AxisType.Auto``: the repo
+    shards through GSPMD propagation and ``with_sharding_constraint`` and
+    never uses ``Explicit`` sharding.
+
+``shard_map(f, mesh=..., in_specs=..., out_specs=...)``
+    ``jax.shard_map``.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 
-__all__ = [
-    "pallas_tpu_compiler_params",
-    "make_mesh",
-    "mesh_axis_types",
-    "shard_map",
-]
+__all__ = ["make_mesh", "shard_map"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` (new name) or ``jax.experimental.shard_map`` (old)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """Build TPU Pallas compiler params across the rename, or None."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:  # pragma: no cover - pallas not bundled
-        return None
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:  # pragma: no cover - very old pallas
-        return None
-    return cls(**kwargs)
-
-
-def mesh_axis_types(n: int) -> Optional[tuple]:
-    """``(AxisType.Auto,) * n`` where AxisType exists, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types on JAX versions that have them."""
-    types = mesh_axis_types(len(tuple(axis_names)))
-    if types is not None:
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    # pragma: no cover — pre-0.4.35 fallback
-    from jax.experimental import mesh_utils
-
-    devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-    return jax.sharding.Mesh(devices, tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(axis_shapes, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))

@@ -96,11 +96,6 @@ Environment knobs
                              xla/tiled/ref backends (see "Machine-checked
                              invariants" above); off by default and
                              structurally free when off.
-``REPRO_GRMAC_BF16_VALUES=1``  run the values einsums of the xla/tiled
-                             backends with bf16 operands + f32 accumulator
-                             when the formats make every product exact
-                             (silent f32 fallback otherwise; see
-                             kernels/xla.py for the caveat on accelerators).
 
 All backends implement the same semantics contract and are cross-checked
 at 0-ulp tolerance in tests/test_kernels.py and tests/test_properties.py;

@@ -21,8 +21,8 @@ backend            implementation
                    the tile body; the large-M winner (>=2x over both
                    ``xla`` and ``ref`` at train_large_m on CPU)
 ``pallas``         ``grmac_matmul.grmac_matmul_pallas`` — the TPU kernel
-                   (VMEM-streaming MXU lowering); off-TPU it silently runs
-                   in interpret mode, so only pick it explicitly on TPU
+                   (VMEM-streaming MXU lowering); it compiles for the TPU
+                   only and fails to lower anywhere else
 ``pallas_interpret``  the Pallas kernel forced through the interpreter —
                    a *debug* backend for cross-checking the TPU lowering's
                    semantics off-TPU; orders of magnitude slower than
@@ -42,7 +42,7 @@ to a ``Plan(backend, tile_m, tile_n)``:
    file carries a schema ``version`` (``PLAN_CACHE_VERSION``); caches
    written under a different version — or pre-versioned flat files — are
    ignored with a warning and rewritten on the next persisted plan, so
-   growing the candidate space (tile_n, bf16-values) can never silently
+   growing the candidate space (e.g. tile_n) can never silently
    serve stale measurements;
 3. with ``REPRO_GRMAC_AUTOTUNE=1``: a micro-autotune that times each
    candidate ``(backend, tile_m, tile_n)`` on synthetic operands of the
@@ -92,15 +92,10 @@ __all__ = [
 BACKENDS = ("auto", "xla", "tiled", "pallas", "pallas_interpret", "ref")
 
 _ENV_VAR = "REPRO_GRMAC_BACKEND"
-# Opt-in bf16 values-einsum variant of the XLA/tiled backends (products exact
-# when the operand formats carry <= 8 significand bits between them; see
-# kernels/xla.py for the accumulation-order caveat). Read per call so tests
-# can monkeypatch the environment.
-_BF16_ENV = "REPRO_GRMAC_BF16_VALUES"
 # Opt-in numerics sanitizer (repro.analysis.sanitize): instruments the
 # xla/tiled/ref backends with in-graph NaN/Inf, pre-ADC overflow and
-# gain-range-limit checks via jax.debug.callback. Read per call (like the
-# bf16 flag) so tests can monkeypatch the environment; when unset the
+# gain-range-limit checks via jax.debug.callback. Read per call so tests
+# can monkeypatch the environment; when unset the
 # backends receive sanitize=False / tag="" and stage *zero* extra
 # primitives (bit-identical outputs, same jit cache keys as before).
 # The Pallas backends are not instrumented (checks cannot run inside a
@@ -111,7 +106,7 @@ _AUTOTUNE_ENV = "REPRO_GRMAC_AUTOTUNE"
 # Override for the persisted plan-cache location.
 _PLAN_CACHE_ENV = "REPRO_GRMAC_PLAN_CACHE"
 # Plan-cache schema version. Bump when the plan record or the candidate
-# space changes meaning (e.g. tile_n semantics, bf16-values candidates):
+# space changes meaning (e.g. tile_n semantics):
 # a cache written by a different schema is ignored with a warning rather
 # than silently serving plans measured under different rules, and the
 # next persisted plan rewrites the file under the current version.
@@ -261,10 +256,9 @@ def _run_plan(x, wq, plan: Plan, kwargs, tag: str = "") -> jax.Array:
         wp = pad_to_multiple(pad_to_multiple(wq, 0, bk), 1, bn)
         out = grmac_matmul_pallas(
             xp, wp, block_m=bm, block_n=bn, block_k=bk,
-            interpret=(True if b == "pallas_interpret" else None), **kwargs)
+            interpret=(b == "pallas_interpret"), **kwargs)
         return out[:m, :n]
 
-    bf16 = os.environ.get(_BF16_ENV, "0") not in ("", "0")
     san = os.environ.get(_SAN_ENV, "0") not in ("", "0")
     # tag="" when the sanitizer is off: the site label is only consumed by
     # sanitize reports, and keeping it constant avoids one jit cache entry
@@ -274,10 +268,9 @@ def _run_plan(x, wq, plan: Plan, kwargs, tag: str = "") -> jax.Array:
     wp = pad_to_multiple(wq, 0, kwargs["n_r"])
     if b == "tiled":
         return grmac_matmul_tiled(xp, wp, tile_m=plan.tile_m,
-                                  tile_n=plan.tile_n, bf16_values=bf16,
-                                  **san_kw, **kwargs)
+                                  tile_n=plan.tile_n, **san_kw, **kwargs)
     if b == "xla":
-        return grmac_matmul_xla(xp, wp, bf16_values=bf16, **san_kw, **kwargs)
+        return grmac_matmul_xla(xp, wp, **san_kw, **kwargs)
     return grmac_matmul_ref(xp, wp, **san_kw, **kwargs)
 
 
@@ -314,13 +307,10 @@ def _probe(key, m, k, n, granularity, fmt_x, fmt_w, n_r, enob) -> Plan:
 
 
 def _tracing() -> bool:
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:
-        # jax without trace_state_clean: we cannot tell, and probing inside
-        # a trace would stage timing runs into the caller's graph — the safe
-        # degradation is to skip probing (heuristic/cache still apply).
-        return True
+    """True inside a jax trace, where probing would stage timing runs into
+    the caller's graph (the heuristic/cache answer there instead)."""
+    from jax._src import core  # trace_state_clean is not public API
+    return not core.trace_state_clean()
 
 
 def plan_for(

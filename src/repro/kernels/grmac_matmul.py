@@ -24,9 +24,15 @@ Shapes must be pre-padded to multiples of the block sizes (see
 grid tiles M the same way the host-side tiled backend does); ``block_k``
 must be a multiple of ``n_r`` and 128-aligned for the MXU. The per-column
 epilogue (den -> ADC -> renorm -> accumulate) is fused in the kernel body,
-matching kernels/tiled.py's formulation, and the K sub-block loop rolls
-into a ``fori_loop`` past ``_UNROLL_SUBBLOCKS`` columns so large planned
-K-tiles don't blow up the lowered kernel.
+matching kernels/tiled.py's formulation. The ``block_k / n_r`` columns of a
+K-tile are unrolled with static slices: Mosaic has no lowering for a slice
+at a traced offset, and dispatch sizes ``block_k = lcm(128, n_r)``, so the
+unrolled body stays small.
+
+The kernel only ever compiles for the TPU. Off-TPU, pass
+``interpret=True`` (the ``pallas_interpret`` dispatch backend) to run it
+through the Pallas interpreter; ``interpret=None`` there is an error, so no
+path falls into the interpreter by accident.
 """
 from __future__ import annotations
 
@@ -36,16 +42,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 from repro.core.formats import FPFormat
 
 __all__ = ["grmac_matmul_pallas"]
-
-# Sub-block (n_r-deep column) count up to which the K loop is fully
-# unrolled into straight-line MXU dots; beyond it a lax.fori_loop keeps the
-# lowered kernel size O(1) in block_k (plans may pick large K-tiles).
-_UNROLL_SUBBLOCKS = 8
 
 
 def _pow2(e: jax.Array) -> jax.Array:
@@ -120,35 +121,26 @@ def _kernel(
         """One n_r-deep analog column: MXU dot + fused den/ADC/renorm
         epilogue — the same per-tile formulation kernels/tiled.py scans on
         the host side, so the TPU lowering matches the planned backend."""
-        xs = jax.lax.dynamic_slice_in_dim(xq16, start, n_r, axis=1)
-        ws = jax.lax.dynamic_slice_in_dim(w16, start, n_r, axis=0)
-        num = jnp.dot(xs, ws, preferred_element_type=jnp.float32)
+        cols = slice(start, start + n_r)
+        num = jnp.dot(xq16[:, cols], w16[cols, :],
+                      preferred_element_type=jnp.float32)
         if granularity == "conv":
             v = num * (1.0 / n_r)
             return acc + _adc(v, enob) * float(n_r)
         if granularity == "row":
-            gs = jax.lax.dynamic_slice_in_dim(gx, start, n_r, axis=1)
-            den = jnp.sum(gs, axis=1, keepdims=True)             # (bm, 1)
+            den = jnp.sum(gx[:, cols], axis=1, keepdims=True)      # (bm, 1)
             scale = 2.0**fmt_x.e_max
             v = num * scale / den
             return acc + _adc(v, enob) * (den * (1.0 / scale))
-        gs = jax.lax.dynamic_slice_in_dim(gx, start, n_r, axis=1)
-        gws = jax.lax.dynamic_slice_in_dim(gw, start, n_r, axis=0)
-        den = jnp.dot(gs, gws, preferred_element_type=jnp.float32)
+        den = jnp.dot(gx[:, cols], gw[cols, :],
+                      preferred_element_type=jnp.float32)
         scale = 2.0 ** (fmt_x.e_max + fmt_w.e_max)
         v = num * scale / den
         return acc + _adc(v, enob) * (den * (1.0 / scale))
 
     acc = jnp.zeros_like(o_ref)
-    n_sub = block_k // n_r
-    if n_sub <= _UNROLL_SUBBLOCKS:
-        for s in range(n_sub):
-            acc = sub_block(s * n_r, acc)
-    else:
-        # Large planned K-tiles: a rolled loop keeps the lowered kernel
-        # O(1) in block_k instead of unrolling hundreds of sub-blocks.
-        acc = jax.lax.fori_loop(
-            0, n_sub, lambda s, a: sub_block(s * n_r, a), acc)
+    for s in range(block_k // n_r):
+        acc = sub_block(s * n_r, acc)
     o_ref[...] += acc
 
 
@@ -191,7 +183,13 @@ def grmac_matmul_pallas(
     assert block_k % n_r == 0
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                "grmac_matmul_pallas compiles for the TPU only, and the "
+                f"default backend is {jax.default_backend()!r}: pass "
+                "interpret=True (the 'pallas_interpret' backend) to run the "
+                "kernel through the Pallas interpreter")
+        interpret = False
 
     kernel = functools.partial(
         _kernel,
@@ -203,14 +201,6 @@ def grmac_matmul_pallas(
         block_k=block_k,
     )
     grid = (m // block_m, n // block_n, k // block_k)
-    call_kwargs = {}
-    if not interpret:
-        # interpret mode ignores TPU compiler params (and some JAX versions
-        # reject them there); only attach them for real TPU lowering.
-        params = pallas_tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if params is not None:
-            call_kwargs["compiler_params"] = params
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -221,5 +211,6 @@ def grmac_matmul_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-        **call_kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x.astype(jnp.float32), wq.astype(jnp.float32))

@@ -27,9 +27,7 @@ Numerics: each tile computes *exactly* the per-element expressions of
 einsum contraction over the ``n_r`` block, same block-sum reduction order),
 so the output is bit-identical to the oracle at 0 ulp on every granularity
 — asserted across tile shapes in tests/test_kernels.py and
-tests/test_properties.py.  The ``bf16_values`` variant mirrors
-``xla.py`` (exact products when the operand formats carry <= 8 significand
-bits combined; silent f32 fallback otherwise).
+tests/test_properties.py.
 
 Tile-size defaults target a ~12 MiB slab (``default_tile_m`` /
 ``_SLAB_BUDGET_BYTES``, the measured CPU sweet spot); the dispatch layer
@@ -46,8 +44,6 @@ from jax import lax
 
 from repro.core.formats import FPFormat, decompose, pow2i, quantize
 from repro.core.mac import adc_quantize
-
-from .xla import bf16_products_exact
 
 __all__ = ["grmac_matmul_tiled", "default_tile_m", "pad_to_multiple"]
 
@@ -84,7 +80,7 @@ def pad_to_multiple(x: jax.Array, axis: int, mult: int) -> jax.Array:
 @functools.partial(
     jax.jit,
     static_argnames=("fmt_x", "fmt_w", "n_r", "enob", "granularity",
-                     "tile_m", "tile_n", "bf16_values", "sanitize", "tag"),
+                     "tile_m", "tile_n", "sanitize", "tag"),
 )
 def grmac_matmul_tiled(
     x: jax.Array,
@@ -97,7 +93,6 @@ def grmac_matmul_tiled(
     granularity: str = "row",
     tile_m: int = 0,
     tile_n: int = 0,
-    bf16_values: bool = False,
     sanitize: bool = False,
     tag: str = "",
 ) -> jax.Array:
@@ -125,12 +120,8 @@ def grmac_matmul_tiled(
     if sanitize:
         from repro.analysis import sanitize as _san
 
-    op_dtype = (jnp.bfloat16 if bf16_values and bf16_products_exact(
-        fmt_x, fmt_w) else jnp.float32)
-
     def block_einsum(a, bb):
-        return jnp.einsum("mbk,bkn->mbn", a.astype(op_dtype),
-                          bb.astype(op_dtype),
+        return jnp.einsum("mbk,bkn->mbn", a, bb,
                           preferred_element_type=jnp.float32)
 
     def _exponents(g):

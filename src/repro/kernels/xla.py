@@ -22,27 +22,6 @@ straight-through convention of ``jnp.round``). Interpret-mode Pallas runs
 the same shapes ~3 orders of magnitude slower; ``benchmarks/kernel_bench.py
 --backend all`` measures the gap.
 
-bf16 values-einsum variant (``bf16_values=True``, reached via
-``REPRO_GRMAC_BF16_VALUES=1`` through ``dispatch.py``): the matmul operands
-carry very few significant bits — quantized inputs have ``n_man_x + 1`` and
-format-grid weights ``n_man_w + 1`` — so each elementwise *product* is
-exactly representable in bfloat16's 8 significand bits whenever
-``(n_man_x + 1) + (n_man_w + 1) <= 8`` (e.g. FP6_E3M2 × FP4_E2M1 = 5 bits).
-The values/gains einsums then run with bf16 operands and
-``preferred_element_type=float32``, which on MXU/tensor-core hardware hits
-the fast mixed-precision GEMM path at zero rounding cost in the multiply.
-Formats that don't satisfy the bound silently fall back to f32 operands, so
-the flag can never change numerics through the multiply itself.
-
-Accumulation-order caveat: the products are exact, but the f32 *sums* over
-each ``n_r`` block are only bit-identical to ``ref.py`` if XLA reduces both
-GEMMs in the same order. On CPU both lower to the same f32 GEMM (bf16
-operands are upcast first), so the cross-backend tests hold 0-ulp equality;
-on TPU/GPU the mixed-precision GEMM may tile its f32 accumulator
-differently at large K, where agreement degrades to last-ulp differences
-*before* ADC quantization (``adc_quantize`` snaps most of those away, but
-values that land on ADC decision boundaries can flip a code). The
-bit-exactness contract is therefore asserted on CPU only.
 """
 from __future__ import annotations
 
@@ -54,23 +33,13 @@ import jax.numpy as jnp
 from repro.core.formats import FPFormat, decompose, pow2i, quantize
 from repro.core.mac import adc_quantize
 
-__all__ = ["grmac_matmul_xla", "bf16_products_exact"]
-
-
-def bf16_products_exact(fmt_x, fmt_w) -> bool:
-    """True when every x·w product fits bfloat16's 8 significand bits, so
-    the bf16 values-einsum variant is lossless (see module docstring)."""
-    nx = getattr(fmt_x, "n_man", None)
-    nw = getattr(fmt_w, "n_man", None)
-    if nx is None or nw is None:      # IntFormat operands: no such bound
-        return False
-    return (nx + 1) + (nw + 1) <= 8
+__all__ = ["grmac_matmul_xla"]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("fmt_x", "fmt_w", "n_r", "enob", "granularity",
-                     "bf16_values", "sanitize", "tag"),
+                     "sanitize", "tag"),
 )
 def grmac_matmul_xla(
     x: jax.Array,
@@ -81,7 +50,6 @@ def grmac_matmul_xla(
     n_r: int = 32,
     enob: float = 8.0,
     granularity: str = "row",
-    bf16_values: bool = False,
     sanitize: bool = False,
     tag: str = "",
 ) -> jax.Array:
@@ -89,8 +57,6 @@ def grmac_matmul_xla(
 
     Inputs pre-scaled to [-1, 1]; ``wq`` already on the weight format grid;
     ``K`` must be a multiple of ``n_r`` (dispatch.py pads).
-    ``bf16_values`` runs the block einsums with bf16 operands and an f32
-    accumulator when the formats make the products exact (no-op otherwise).
     ``sanitize`` stages the ``repro.analysis.sanitize`` checks on the
     pre-ADC voltage / exponent spans, reported under ``tag``; when False
     (the default) the staged graph is exactly the uninstrumented one.
@@ -104,12 +70,8 @@ def grmac_matmul_xla(
     if sanitize:
         from repro.analysis import sanitize as _san
 
-    op_dtype = (jnp.bfloat16 if bf16_values and bf16_products_exact(
-        fmt_x, fmt_w) else jnp.float32)
-
     def block_einsum(a, bb):
-        return jnp.einsum("mbk,bkn->mbn", a.astype(op_dtype),
-                          bb.astype(op_dtype),
+        return jnp.einsum("mbk,bkn->mbn", a, bb,
                           preferred_element_type=jnp.float32)
 
     xq = quantize(x, fmt_x)
@@ -149,7 +111,6 @@ def grmac_matmul_xla(
         gwb = pow2i(ew).reshape(b, n_r, n)
         with jax.named_scope("cim_values"):
             num = block_einsum(xb, wb)
-        # gains are powers of two: their bf16 products are exact too
         with jax.named_scope("cim_gains"):
             den = block_einsum(gxb, gwb)
         scale = 2.0 ** (fmt_x.e_max + fmt_w.e_max)

@@ -23,6 +23,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import init_params
 from repro.serving.engine import Engine, ServeConfig, energy_report
@@ -103,6 +104,7 @@ def main():
                          "config itself")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_config(args.arch)
     if args.reduced:

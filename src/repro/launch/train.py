@@ -19,6 +19,7 @@ import argparse
 import json
 import tempfile
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch.mesh import make_local_mesh, make_production_mesh
@@ -49,6 +50,7 @@ def main():
     ap.add_argument("--drill-faults", type=int, default=3)
     ap.add_argument("--drill-seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_config(args.arch)
     if args.reduced:

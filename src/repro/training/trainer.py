@@ -20,9 +20,12 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.models import init_params, train_loss
+from repro.parallel.sharding import (
+    batch_axes, get_mesh, named_sharding_tree, param_specs)
 from repro.training import checkpoint as ckpt
 from repro.training.fault import Heartbeat, HeartbeatBoard
 from repro.training.optimizer import (
@@ -33,7 +36,7 @@ from repro.training.optimizer import (
     init_opt_state,
 )
 
-__all__ = ["TrainConfig", "make_train_step", "train"]
+__all__ = ["TrainConfig", "make_train_step", "init_train_state", "train"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,6 +116,38 @@ def make_train_step(arch: ArchConfig, tcfg: TrainConfig) -> Callable:
     return train_step
 
 
+def init_train_state(arch: ArchConfig, tcfg: TrainConfig, seed: int = 0):
+    """Initial params, optimizer state and the jitted train step, placed
+    for the active mesh (``parallel.sharding.use_mesh``).
+
+    Returns ``(params, opt_state, step_fn, shardings)``. Without a mesh
+    everything lives on the default device and ``shardings`` is None.
+    Under a mesh, params and optimizer state are created directly in their
+    ``param_specs`` shardings (FSDP over the data axes, TP over "model"),
+    the step is jitted with matching in/out shardings so the state stays
+    put from step to step (batches are split over the data axes), and
+    ``shardings`` is the ``(params, opt_state)`` NamedSharding pair."""
+    key = jax.random.PRNGKey(seed)
+    init_p = lambda k: init_params(k, arch)
+    init_o = lambda p: init_opt_state(p, tcfg.opt)
+    mesh = get_mesh()
+    if mesh is None:
+        params = init_p(key)
+        return (params, init_o(params), jax.jit(make_train_step(arch, tcfg)),
+                None)
+    p_shape = jax.eval_shape(init_p, key)
+    p_sh = named_sharding_tree(param_specs(p_shape, mesh), mesh)
+    o_sh = named_sharding_tree(
+        param_specs(jax.eval_shape(init_o, p_shape), mesh), mesh)
+    b_sh = NamedSharding(mesh, P(batch_axes(mesh)))
+    params = jax.jit(init_p, out_shardings=p_sh)(key)
+    opt_state = jax.jit(init_o, out_shardings=o_sh)(params)
+    step_fn = jax.jit(make_train_step(arch, tcfg),
+                      in_shardings=(p_sh, o_sh, b_sh),
+                      out_shardings=(p_sh, o_sh, NamedSharding(mesh, P())))
+    return params, opt_state, step_fn, (p_sh, o_sh)
+
+
 def train(
     arch: ArchConfig,
     tcfg: TrainConfig,
@@ -120,17 +155,18 @@ def train(
     *,
     seed: int = 0,
     heartbeat_dir: Optional[str] = None,
-    jit_kwargs: Optional[dict] = None,
 ) -> dict:
     """Run (or resume) training; returns final metrics.
+
+    State placement follows ``init_train_state``: sharded over the active
+    mesh when there is one.
 
     Checkpointing is asynchronous and (optionally) two-tier: a background
     writer thread publishes atomic snapshots while training proceeds —
     the loop only pays the host snapshot (see
     ``checkpoint.AsyncCheckpointer``). Resume picks the freshest valid
     step across tiers, falling back past corrupt ones."""
-    params = init_params(jax.random.PRNGKey(seed), arch)
-    opt_state = init_opt_state(params, tcfg.opt)
+    params, opt_state, step_fn, shardings = init_train_state(arch, tcfg, seed)
     start_step = 0
 
     writer = None
@@ -143,11 +179,13 @@ def train(
             state, start_step, tier = writer.restore(
                 {"params": params, "opt": opt_state})
             params, opt_state = state["params"], state["opt"]
+            if shardings is not None:
+                params, opt_state = jax.device_put((params, opt_state),
+                                                   shardings)
             print(f"[train] resumed from step {start_step} ({tier} tier)")
         except FileNotFoundError:
             pass  # cold start
 
-    step_fn = jax.jit(make_train_step(arch, tcfg), **(jit_kwargs or {}))
     board = HeartbeatBoard(heartbeat_dir) if heartbeat_dir else None
 
     metrics = {}

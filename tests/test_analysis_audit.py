@@ -39,7 +39,7 @@ def test_paper_arch_decode_audits_clean():
 
 def test_train_grad_audits_clean_with_transposes_excluded():
     arch = get_config("qwen2-1.5b").reduced()
-    res = audit_arch(arch, ("train",), bf16_regime_check=False)
+    res = audit_arch(arch, ("train",))
     ph = res["phases"]["train"]
     assert res["failures"] == 0, ph
     assert ph["transposes"] > 0         # grad transposes seen, not counted

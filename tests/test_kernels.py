@@ -21,7 +21,7 @@ from repro.kernels.grmac_matmul import grmac_matmul_pallas
 from repro.kernels.ops import cim_matmul
 from repro.kernels.ref import grmac_matmul_ref
 from repro.kernels.tiled import default_tile_m, grmac_matmul_tiled
-from repro.kernels.xla import bf16_products_exact, grmac_matmul_xla
+from repro.kernels.xla import grmac_matmul_xla
 
 
 def _data(key, m, k, n):
@@ -128,47 +128,6 @@ def test_tiled_through_dispatch_unpadded():
     tiny = grmac_matmul(x, w, backend="tiled", tile_m=16, tile_n=8, **kw)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     np.testing.assert_array_equal(np.asarray(tiny), np.asarray(ref))
-
-
-# ----------------------------------------------------- bf16 values variant
-@pytest.mark.parametrize("granularity", ["conv", "row", "unit"])
-def test_xla_bf16_values_matches_ref_exactly(granularity):
-    """FP6_E3M2 x FP4_E2M1 products carry 5 significand bits, so the bf16
-    values-einsum variant must agree with the oracle at 0 ulp (CPU
-    contract; see the accumulation-order caveat in kernels/xla.py)."""
-    assert bf16_products_exact(FP6_E3M2, FP4_E2M1)
-    x, w = _data(jax.random.PRNGKey(11), 64, 256, 48)
-    kw = dict(fmt_x=FP6_E3M2, fmt_w=FP4_E2M1, n_r=32, enob=8.0,
-              granularity=granularity)
-    ref = grmac_matmul_ref(x, w, **kw)
-    out = grmac_matmul_xla(x, w, bf16_values=True, **kw)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_xla_bf16_values_env_flag(monkeypatch):
-    """REPRO_GRMAC_BF16_VALUES=1 routes dispatch through the bf16 variant
-    and keeps the 0-ulp cross-backend contract on every granularity."""
-    monkeypatch.setenv("REPRO_GRMAC_BF16_VALUES", "1")
-    x, w = _data(jax.random.PRNGKey(12), 7, 100, 13)  # unpadded shapes too
-    for gran in ["conv", "row", "unit"]:
-        kw = dict(fmt_x=FP6_E3M2, fmt_w=FP4_E2M1, n_r=32, enob=8.0,
-                  granularity=gran)
-        ref = grmac_matmul(x, w, backend="ref", **kw)
-        out = grmac_matmul(x, w, backend="xla", **kw)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_xla_bf16_values_falls_back_for_wide_formats():
-    """Formats whose products exceed bf16's 8 significand bits must ignore
-    the flag (silent f32 fallback keeps numerics unconditionally safe)."""
-    wide = FPFormat(3, 6)          # 7 + 2 significand bits > 8
-    assert not bf16_products_exact(wide, FP4_E2M1)
-    x, w = _data(jax.random.PRNGKey(13), 32, 128, 16)
-    kw = dict(fmt_x=wide, fmt_w=FP4_E2M1, n_r=32, enob=8.0,
-              granularity="row")
-    ref = grmac_matmul_xla(x, w, bf16_values=False, **kw)
-    out = grmac_matmul_xla(x, w, bf16_values=True, **kw)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 # ---------------------------------------------------------------- dispatch
@@ -321,6 +280,17 @@ def test_grmac_with_intformat_raises_not_implemented(backend):
                                      n_r=32, fmt_x=IntFormat(8)))
     assert out.shape == (16, 8)
     assert bool(jnp.all(jnp.isfinite(out)))
+
+
+def test_pallas_never_falls_back_to_the_interpreter():
+    """Off-TPU the Pallas kernel runs only when the interpreter is asked
+    for by name (``pallas_interpret``): the default is an error."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the kernel compiles natively on TPU")
+    x, w = _data(jax.random.PRNGKey(4), 128, 128, 128)
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        grmac_matmul_pallas(x, w, fmt_x=FP6_E3M2, fmt_w=FP4_E2M1, n_r=32,
+                            enob=8.0, granularity="row")
 
 
 # ----------------------------------------- Pallas interpret-mode cross-check

@@ -56,14 +56,12 @@ def test_grmac_ideal_adc_equals_exact_quantized_product(seed, gran):
        blocks=st.integers(1, 4),
        n_r=st.sampled_from([4, 8, 32, 96]),   # one element shy .. whole K
        tile_m=st.sampled_from([1, 8, 13, 32, 256]),
-       tile_n=st.sampled_from([0, 8, 13]),
-       bf16=st.booleans())
+       tile_n=st.sampled_from([0, 8, 13]))
 def test_tiled_bit_identical_to_ref(seed, gran, m, n, blocks, n_r,
-                                    tile_m, tile_n, bf16):
+                                    tile_m, tile_n):
     """The fused tiled backend is the oracle, bit for bit (0 ulp), across
-    granularities, tile sizes that do and don't divide M/N, n_r edge
-    values (one block per row through many narrow columns), and the bf16
-    values-einsum flag (FP6_E3M2 x FP4_E2M1 products are bf16-exact)."""
+    granularities, tile sizes that do and don't divide M/N, and n_r edge
+    values (one block per row through many narrow columns)."""
     k = blocks * n_r
     kx, kw_ = jax.random.split(jax.random.PRNGKey(seed))
     x = jax.random.uniform(kx, (m, k), minval=-1, maxval=1)
@@ -72,8 +70,7 @@ def test_tiled_bit_identical_to_ref(seed, gran, m, n, blocks, n_r,
     kw = dict(fmt_x=F.FP6_E3M2, fmt_w=F.FP4_E2M1, n_r=n_r, enob=8.0,
               granularity=gran)
     ref = grmac_matmul_ref(x, w, **kw)
-    out = grmac_matmul_tiled(x, w, tile_m=tile_m, tile_n=tile_n,
-                             bf16_values=bf16, **kw)
+    out = grmac_matmul_tiled(x, w, tile_m=tile_m, tile_n=tile_n, **kw)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 

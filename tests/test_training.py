@@ -263,3 +263,48 @@ def test_elastic_end_to_end(tmp_path):
     for s in range(step, step + 2):
         p, o, m = stepper(p, o, pipe.batch_at(s))
         assert np.isfinite(float(m["loss"]))
+
+
+_FSDP_PLACEMENT_SNIPPET = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+from repro.compat import make_mesh
+from repro.configs import get_config
+from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.parallel.sharding import use_mesh
+from repro.training.optimizer import OptimizerConfig
+from repro.training.trainer import TrainConfig, init_train_state, train
+
+arch = get_config("qwen2-1.5b").reduced().replace(n_layers=2)
+pipe = SyntheticLM(DataConfig(global_batch=8, seq_len=16,
+                              vocab_size=arch.vocab_size))
+tcfg = TrainConfig(steps=2, log_every=100,
+                   opt=OptimizerConfig(lr=1e-3, warmup_steps=1, total_steps=2))
+with use_mesh(make_mesh((4, 1), ("data", "model"))):
+    params, opt_state, _, _ = init_train_state(arch, tcfg)
+    n_sharded = 0
+    for leaf in jax.tree.leaves((params, opt_state)):
+        if "data" in str(leaf.sharding.spec):
+            n_sharded += 1
+            assert {s.data.size * 4 for s in leaf.addressable_shards} == {
+                leaf.size}, (leaf.shape, leaf.sharding.spec)
+    assert n_sharded > 0
+    sharded = train(arch, tcfg, pipe)
+single = train(arch, tcfg, pipe)
+assert abs(sharded["loss"] - single["loss"]) < 1e-4, (sharded, single)
+print("OK", n_sharded)
+"""
+
+
+def test_train_places_state_on_the_mesh():
+    """Under a (data, model) = (4, 1) mesh, ``train`` creates params and
+    optimizer state in their FSDP shardings (each device holds a quarter of
+    every data-sharded leaf) and reaches the unsharded run's loss."""
+    import subprocess
+    import sys
+    out = subprocess.run([sys.executable, "-c", _FSDP_PLACEMENT_SNIPPET],
+                         capture_output=True, text=True, timeout=600,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "OK" in out.stdout
