@@ -1,0 +1,11 @@
+"""Puts the checkout's root and ``src`` on the path for the benchmark's
+CPU tests."""
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
