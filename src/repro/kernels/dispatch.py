@@ -73,7 +73,7 @@ import jax
 
 from repro.core.formats import FPFormat
 
-from .grmac_matmul import grmac_matmul_pallas
+from .grmac_matmul import grmac_matmul_pallas, named_kernel
 from .ref import grmac_matmul_ref
 from .tiled import default_tile_m, grmac_matmul_tiled, pad_to_multiple
 from .xla import grmac_matmul_xla
@@ -254,7 +254,10 @@ def _run_plan(x, wq, plan: Plan, kwargs, tag: str = "") -> jax.Array:
         m, n = x.shape[0], wq.shape[1]
         xp = pad_to_multiple(pad_to_multiple(x, 0, bm), 1, bk)
         wp = pad_to_multiple(pad_to_multiple(wq, 0, bk), 1, bn)
-        out = grmac_matmul_pallas(
+        # a named call site launches under its own name (``%grmac_<site>``
+        # in the device trace); only the launch, not the padding around it
+        kernel = named_kernel(f"grmac_{tag}") if tag else grmac_matmul_pallas
+        out = kernel(
             xp, wp, block_m=bm, block_n=bn, block_k=bk,
             interpret=(b == "pallas_interpret"), **kwargs)
         return out[:m, :n]
@@ -372,7 +375,8 @@ def grmac_matmul(
     ``x`` pre-scaled to [-1, 1]; ``wq`` already on the weight format grid.
     Arbitrary M/N/K (padding handled here); float32 output. ``tile_m`` /
     ``tile_n`` override the plan's tile sizes (``tiled``/``pallas`` only).
-    ``tag`` names the call site in ``REPRO_SANITIZE=1`` violation reports
+    ``tag`` names the call site: in ``REPRO_SANITIZE=1`` violation reports,
+    and as the Pallas launch's name (``grmac_<tag>``) in TPU traces
     (metadata only; never changes numerics or planning).
     """
     m, k = x.shape

@@ -46,7 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import FPFormat
 
-__all__ = ["grmac_matmul_pallas"]
+__all__ = ["grmac_matmul_pallas", "named_kernel"]
 
 
 def _pow2(e: jax.Array) -> jax.Array:
@@ -144,21 +144,11 @@ def _kernel(
     o_ref[...] += acc
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "fmt_x",
-        "fmt_w",
-        "n_r",
-        "enob",
-        "granularity",
-        "block_m",
-        "block_n",
-        "block_k",
-        "interpret",
-    ),
-)
-def grmac_matmul_pallas(
+_STATIC = ("fmt_x", "fmt_w", "n_r", "enob", "granularity", "block_m",
+           "block_n", "block_k", "interpret")
+
+
+def _grmac_pallas(
     x: jax.Array,
     wq: jax.Array,
     *,
@@ -214,3 +204,24 @@ def grmac_matmul_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x.astype(jnp.float32), wq.astype(jnp.float32))
+
+
+@functools.lru_cache(maxsize=None)
+def named_kernel(name: str):
+    """The kernel's jitted entry point under ``name``.
+
+    On the TPU a launch's HLO instruction, and so its event in a device
+    trace, takes the name of the innermost jitted function around its
+    ``pallas_call`` (``%<name>.N``); neither a ``named_scope``/``named_call``
+    nor an outer jitted wrapper changes it. ``dispatch`` launches each model
+    call site's matmuls under ``grmac_<site>`` for that reason. The name
+    changes nothing else: every name runs the same kernel body."""
+    def fn(*args, **kwargs):
+        return _grmac_pallas(*args, **kwargs)
+
+    functools.update_wrapper(fn, _grmac_pallas)
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, static_argnames=_STATIC)
+
+
+grmac_matmul_pallas = named_kernel("grmac_matmul_pallas")

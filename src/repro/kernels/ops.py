@@ -80,7 +80,8 @@ def site_marker(site: Optional[str], m: int, k: int, n: int) -> str:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _cim_matmul_2d(x, w, cfg: CIMConfig, backend: str, site: str):
     """(M, K) @ (K, N) with CIM numerics and STE gradients. ``site`` is
-    metadata only (sanitizer context tag); it never changes numerics."""
+    metadata only (sanitizer context tag, and the name of the Pallas
+    launch in a TPU trace); it never changes numerics."""
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
     w32 = w.astype(jnp.float32)

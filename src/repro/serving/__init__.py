@@ -98,6 +98,10 @@ family: accepted-tokens/step, TTLT speedup, pJ/accepted-token verdict).
 Invariants: ``repro.analysis.invariants`` proves the compile budget and
 one-transfer-per-step rules hold under hand-placed, scheduler-driven,
 prefix-hit-heavy, and speculative serving.
+
+Tracing: ``spans`` puts a ``repro.*`` host span at each layer boundary
+(scheduler step and phases, the engine's dispatches and its one fetch)
+into ``jax.profiler``'s trace, on the device's clock.
 """
 from repro.serving.engine import Engine, ServeConfig, StepResult, energy_report
 from repro.serving.params import RequestOutput, SamplingParams

@@ -135,6 +135,7 @@ from repro.serving.prefix_cache import (
     restore_slot,
     snapshot_slot,
 )
+from repro.serving.spans import span
 
 __all__ = ["ServeConfig", "Engine", "StepResult", "SamplingParams",
            "RequestOutput", "energy_report"]
@@ -231,6 +232,8 @@ def _decode_raw(arch: ArchConfig, sample: bool):
             nxt = jnp.argmax(logits, axis=-1)
         return nxt.astype(jnp.int32), merged
 
+    # the program's name in traces and HLO: jit_decode_sample/_greedy
+    fn.__name__ = "decode_sample" if sample else "decode_greedy"
     return fn
 
 
@@ -252,9 +255,13 @@ def _decode_fn(arch: ArchConfig, sample: bool):
 
 def _prefill_raw(arch: ArchConfig, bucket: int):
     """The unjitted chunked-prefill body for one bucket length (see
-    ``_decode_raw`` for why the raw/jit split exists)."""
-    del bucket  # shapes carry the bucket; the key just partitions the cache
-    return lambda p, t, c, i, l: prefill_step(p, t, arch, c, i, l)
+    ``_decode_raw`` for why the raw/jit split exists). Shapes carry the
+    bucket; it names the program (``jit_prefill_b<bucket>``)."""
+    def fn(p, t, c, i, l):
+        return prefill_step(p, t, arch, c, i, l)
+
+    fn.__name__ = f"prefill_b{bucket}"
+    return fn
 
 
 @functools.lru_cache(maxsize=256)
@@ -284,8 +291,6 @@ def _verify_raw(arch: ArchConfig, bucket: int):
     (accepted drafts then the correction/bonus) followed by the per-lane
     emitted count — one fetch — plus the new cache.
     """
-    del bucket  # shapes carry the bucket; the key just partitions the cache
-
     def fn(params, toks, cache, index, lens, key, temp, seeds, ctrs):
         b, s = toks.shape
         idx = jnp.broadcast_to(jnp.asarray(index), (b,))
@@ -330,6 +335,8 @@ def _verify_raw(arch: ArchConfig, bucket: int):
             [emitted, (m + 1).astype(jnp.int32)[:, None]], axis=1)
         return packed, new_cache
 
+    # shapes carry the bucket; it names the program (jit_verify_b<bucket>)
+    fn.__name__ = f"verify_b{bucket}"
     return fn
 
 
@@ -441,10 +448,18 @@ class Engine:
         # tokens adopted from the prefix cache for the slot's current
         # occupant (0 = cold prefill) — the scheduler's savings counter
         self._adopted = np.zeros(cfg.batch_slots, np.int64)
+        # the caller's request id per slot (-1: none given), carried on the
+        # trace spans of the slot's prefill (repro.serving.spans)
+        self._rid = np.full(cfg.batch_slots, -1, np.int64)
         # prefill_tokens counts prompt tokens actually dispatched (suffix
-        # only, under hits) — the CostLedger's prefill energy multiplier
+        # only, under hits) — the CostLedger's prefill energy multiplier;
+        # prefill_rows counts the rows those dispatches ran (batch_slots x
+        # bucket each, every lane riding along), so tokens / rows is the
+        # share of prefill work that carried a prompt token
+        # (Scheduler.metrics reports both)
         self.stats = {"prefill_dispatches": 0, "decode_steps": 0,
-                      "prefill_tokens": 0, "prefix_hit_tokens": 0,
+                      "prefill_tokens": 0, "prefill_rows": 0,
+                      "prefix_hit_tokens": 0,
                       # speculative-decode counters (speculative.py):
                       # dispatches by kind, iterations, and tokens
                       # emitted through spec steps (accepted incl. the
@@ -564,7 +579,8 @@ class Engine:
     # ------------------------------------------------- incremental prefill
     def begin_request(self, prompt: List[int],
                       eos_id: Optional[int] = None, *,
-                      params: Optional[SamplingParams] = None) -> int:
+                      params: Optional[SamplingParams] = None,
+                      rid: Optional[int] = None) -> int:
         """Claim and validate a free slot for ``prompt`` without running
         any prefill: the lane is *reserved* (``free_slots`` excludes it)
         but not yet in the decode batch. The scheduler drains the prompt
@@ -573,7 +589,8 @@ class Engine:
         begin → advance-until-drained → finish composition of the same
         methods. ``params`` seeds the lane's per-request state (see
         ``add_request``); the ``eos_id`` kwarg is the deprecated legacy
-        spelling."""
+        spelling. ``rid``, the caller's request id, only labels the lane's
+        trace spans."""
         params = self._resolve_params(eos_id, params)
         if not prompt:
             raise ValueError("empty prompt")
@@ -611,6 +628,7 @@ class Engine:
                               else int(params.spec_k))
         self._finish_reason[slot] = None
         self._adopted[slot] = 0
+        self._rid[slot] = -1 if rid is None else int(rid)
         if self.prefix_cache is not None:
             hit = self.prefix_cache.lookup(prompt)
             if hit is not None:
@@ -687,7 +705,9 @@ class Engine:
         sample = self._resolve_sampling(key, slot)
         logits = self._pending_logits.pop(slot)
         del self._pending_prompt[slot]
-        first = self._select_token(logits, slot, sample, key)
+        with span("engine.first_token", slot=slot,
+                  rid=int(self._rid[slot])):
+            first = self._select_token(logits, slot, sample, key)
         self._adopt_first_token(slot, first)
         return first
 
@@ -816,12 +836,13 @@ class Engine:
                 idx[axis] = slot
                 return a.at[tuple(idx)].set(0)
             return f
-        out = dict(self.cache)
-        if "superblocks" in out:
-            out["superblocks"] = jax.tree.map(z(1), out["superblocks"])
-        if "tail" in out:
-            out["tail"] = jax.tree.map(z(0), out["tail"])
-        self.cache = out
+        with span("engine.reset_lane", slot=slot):
+            out = dict(self.cache)
+            if "superblocks" in out:
+                out["superblocks"] = jax.tree.map(z(1), out["superblocks"])
+            if "tail" in out:
+                out["tail"] = jax.tree.map(z(0), out["tail"])
+            self.cache = out
 
     def _bucket(self, n: int) -> int:
         b = self.cfg.prefill_bucket_min
@@ -836,17 +857,21 @@ class Engine:
         last-valid-token logits (B, V) on device (the final chunk's feed
         the first-output-token selection)."""
         bucket = self._bucket(len(chunk))
-        toks = np.zeros((self.cfg.batch_slots, bucket), np.int32)
-        toks[slot, :len(chunk)] = chunk
-        lens = np.zeros(self.cfg.batch_slots, np.int32)
-        lens[slot] = len(chunk)
-        fill = self._compiled_prefill(bucket)
-        logits, _, self.cache = fill(
-            self.params, jnp.asarray(toks), self.cache,
-            self._snapshot(self.lengths), jnp.asarray(lens))
-        self.lengths[slot] += len(chunk)
-        self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_tokens"] += len(chunk)
+        with span("engine.prefill_chunk", slot=slot,
+                  rid=int(self._rid[slot]), tokens=len(chunk),
+                  bucket=bucket):
+            toks = np.zeros((self.cfg.batch_slots, bucket), np.int32)
+            toks[slot, :len(chunk)] = chunk
+            lens = np.zeros(self.cfg.batch_slots, np.int32)
+            lens[slot] = len(chunk)
+            fill = self._compiled_prefill(bucket)
+            logits, _, self.cache = fill(
+                self.params, jnp.asarray(toks), self.cache,
+                self._snapshot(self.lengths), jnp.asarray(lens))
+            self.lengths[slot] += len(chunk)
+            self.stats["prefill_dispatches"] += 1
+            self.stats["prefill_tokens"] += len(chunk)
+            self.stats["prefill_rows"] += self.cfg.batch_slots * bucket
         return logits
 
     def _advance_slot(self, slot: int, token: int, sample: bool = False,
@@ -870,6 +895,7 @@ class Engine:
         self.lengths[slot] += 1
         self.stats["prefill_dispatches"] += 1
         self.stats["prefill_tokens"] += 1
+        self.stats["prefill_rows"] += self.cfg.batch_slots
         return int(self._fetch(ids)[slot])
 
     # ------------------------------------------------------------ decode
@@ -892,6 +918,10 @@ class Engine:
         completed during ``add_request`` itself (first prefill-sampled
         token == EOS) are reported here too, ahead of this step's frees.
         """
+        with span("engine.step"):
+            return self._step(key)
+
+    def _step(self, key: Optional[jax.Array]) -> "StepResult":
         pending, outputs = self._drain_pending()
         if not self.active.any():
             return StepResult({}, pending, self._pj_per_token,
@@ -1013,15 +1043,16 @@ class Engine:
         ``cur[i]`` at cache offset ``offsets[i]``. Non-mask lanes are
         frozen by the in-executable merge exactly like inactive decode
         lanes. Returns the drafted ids (one fetch)."""
-        toks = np.zeros((self.cfg.batch_slots, 1), np.int32)
-        toks[mask, 0] = cur[mask]
-        zero = np.zeros(self.cfg.batch_slots, np.float32)
-        ids_dev, self.cache = self._compiled_draft(draft_arch)(
-            self.params, jnp.asarray(toks), self.cache,
-            self._snapshot(offsets.astype(np.int32)),
-            jnp.asarray(mask.copy()), *self._sampling_args(None, zero))
-        self.stats["draft_dispatches"] += 1
-        return self._fetch(ids_dev)
+        with span("engine.draft"):
+            toks = np.zeros((self.cfg.batch_slots, 1), np.int32)
+            toks[mask, 0] = cur[mask]
+            zero = np.zeros(self.cfg.batch_slots, np.float32)
+            ids_dev, self.cache = self._compiled_draft(draft_arch)(
+                self.params, jnp.asarray(toks), self.cache,
+                self._snapshot(offsets.astype(np.int32)),
+                jnp.asarray(mask.copy()), *self._sampling_args(None, zero))
+            self.stats["draft_dispatches"] += 1
+            return self._fetch(ids_dev)
 
     def verify_chunk(self, chunk: np.ndarray,
                      lens: np.ndarray) -> np.ndarray:
@@ -1033,16 +1064,17 @@ class Engine:
         (B, bucket) target ids (one fetch); the host keeps the longest
         draft prefix matching them. Lengths are NOT committed here — the
         orchestrator commits only accepted tokens."""
-        b, k = chunk.shape
-        bucket = self._bucket(k)
-        toks = np.zeros((b, bucket), np.int32)
-        toks[:, :k] = chunk
-        _, ids_dev, self.cache = self._compiled_prefill(bucket)(
-            self.params, jnp.asarray(toks), self.cache,
-            self._snapshot(self.lengths),
-            jnp.asarray(lens.astype(np.int32).copy()))
-        self.stats["verify_dispatches"] += 1
-        return self._fetch(ids_dev)[:, :k]
+        with span("engine.verify"):
+            b, k = chunk.shape
+            bucket = self._bucket(k)
+            toks = np.zeros((b, bucket), np.int32)
+            toks[:, :k] = chunk
+            _, ids_dev, self.cache = self._compiled_prefill(bucket)(
+                self.params, jnp.asarray(toks), self.cache,
+                self._snapshot(self.lengths),
+                jnp.asarray(lens.astype(np.int32).copy()))
+            self.stats["verify_dispatches"] += 1
+            return self._fetch(ids_dev)[:, :k]
 
     def verify_chunk_sampled(self, chunk: np.ndarray, lens: np.ndarray,
                              key: Optional[jax.Array]):
@@ -1052,20 +1084,21 @@ class Engine:
         tokens (B, bucket) with ``counts[i]`` valid entries per lane —
         from the packed single fetch. Lanes with ``temp <= 0`` get exact
         greedy acceptance, so mixed batches verify in one dispatch."""
-        b, k = chunk.shape
-        bucket = self._bucket(k)
-        toks = np.zeros((b, bucket), np.int32)
-        toks[:, :k] = chunk
-        eff = self._effective_temps(key)
-        packed_dev, self.cache = self._compiled_verify(bucket)(
-            self.params, jnp.asarray(toks), self.cache,
-            self._snapshot(self.lengths),
-            jnp.asarray(lens.astype(np.int32).copy()),
-            *self._sampling_args(key, eff))
-        self._count_sampling_event(eff, lens > 0)
-        self.stats["verify_dispatches"] += 1
-        packed = self._fetch(packed_dev)
-        return packed[:, :k], packed[:, bucket]
+        with span("engine.verify"):
+            b, k = chunk.shape
+            bucket = self._bucket(k)
+            toks = np.zeros((b, bucket), np.int32)
+            toks[:, :k] = chunk
+            eff = self._effective_temps(key)
+            packed_dev, self.cache = self._compiled_verify(bucket)(
+                self.params, jnp.asarray(toks), self.cache,
+                self._snapshot(self.lengths),
+                jnp.asarray(lens.astype(np.int32).copy()),
+                *self._sampling_args(key, eff))
+            self._count_sampling_event(eff, lens > 0)
+            self.stats["verify_dispatches"] += 1
+            packed = self._fetch(packed_dev)
+            return packed[:, :k], packed[:, bucket]
 
     def repair_chunk(self, chunk: np.ndarray, lens: np.ndarray,
                      index: np.ndarray) -> None:
@@ -1077,15 +1110,16 @@ class Engine:
         verify; logits and ids are discarded on device — acceptance
         already knows every token, so repair adds NO fetch (the invariant
         ``run_spec_invariants`` checks)."""
-        b, k = chunk.shape
-        bucket = self._bucket(k)
-        toks = np.zeros((b, bucket), np.int32)
-        toks[:, :k] = chunk
-        _, _, self.cache = self._compiled_prefill(bucket)(
-            self.params, jnp.asarray(toks), self.cache,
-            self._snapshot(index.astype(np.int32)),
-            jnp.asarray(lens.astype(np.int32).copy()))
-        self.stats["repair_dispatches"] += 1
+        with span("engine.repair"):
+            b, k = chunk.shape
+            bucket = self._bucket(k)
+            toks = np.zeros((b, bucket), np.int32)
+            toks[:, :k] = chunk
+            _, _, self.cache = self._compiled_prefill(bucket)(
+                self.params, jnp.asarray(toks), self.cache,
+                self._snapshot(index.astype(np.int32)),
+                jnp.asarray(lens.astype(np.int32).copy()))
+            self.stats["repair_dispatches"] += 1
 
     # ------------------------------------------------------------ energy
     def energy_per_token(self) -> Optional[dict]:
@@ -1113,7 +1147,8 @@ class Engine:
         and prefill first-token selection, or a (batch_slots, bucket[+1])
         int32 array per speculative verify. Repair dispatches cross
         nothing."""
-        return np.asarray(ids_dev)
+        with span("engine.fetch"):
+            return np.asarray(ids_dev)
 
 
 def energy_report(arch: ArchConfig, *, batch: int = 1,

@@ -114,6 +114,7 @@ import numpy as np
 
 from repro.serving.engine import Engine
 from repro.serving.params import SamplingParams
+from repro.serving.spans import span
 
 __all__ = [
     "WAITING", "PREFILLING", "RUNNING", "PREEMPTED", "FINISHED",
@@ -370,25 +371,27 @@ class Scheduler:
                     self._finish(r, "rejected", now, wall)
                 continue
             del self.waiting[idx]
-            p = r.params
-            if r.resume_prompt is not None and p.max_tokens is not None:
-                # recompute resume: tokens generated before eviction are
-                # part of the resume prompt, so the engine-side cap must
-                # count only what is still owed
-                p = p.replace(max_tokens=max(1, p.max_tokens
-                                             - r.n_generated))
-            r.slot = self.engine.begin_request(prompt, params=p)
-            if r.resume_prompt is not None:
-                # preemption recompute that the prefix cache absorbed:
-                # the evicted lane's own boundary snapshots make the
-                # re-prefill a (mostly) cache hit
-                self.stats["recompute_tokens_saved"] += \
-                    self.engine.adopted_prefix(r.slot)
-            r.state = PREFILLING
-            r.t_admit = now
-            r.wall_admit = wall
-            self.prefilling.append(r)
-            self.stats["admitted"] += 1
+            with span("scheduler.admit", rid=r.rid):
+                p = r.params
+                if r.resume_prompt is not None and p.max_tokens is not None:
+                    # recompute resume: tokens generated before eviction are
+                    # part of the resume prompt, so the engine-side cap must
+                    # count only what is still owed
+                    p = p.replace(max_tokens=max(1, p.max_tokens
+                                                 - r.n_generated))
+                r.slot = self.engine.begin_request(prompt, params=p,
+                                                   rid=r.rid)
+                if r.resume_prompt is not None:
+                    # preemption recompute that the prefix cache absorbed:
+                    # the evicted lane's own boundary snapshots make the
+                    # re-prefill a (mostly) cache hit
+                    self.stats["recompute_tokens_saved"] += \
+                        self.engine.adopted_prefix(r.slot)
+                r.state = PREFILLING
+                r.t_admit = now
+                r.wall_admit = wall
+                self.prefilling.append(r)
+                self.stats["admitted"] += 1
             admitted.append(r)
             budget -= 1
         return admitted
@@ -499,7 +502,13 @@ class Scheduler:
         Returns a summary dict (admitted/prefilled/decoded/finished
         counts) for observability; request objects carry the full
         accounting. Pass ``key`` to sample (split per use; greedy
-        otherwise)."""
+        otherwise). Traced as ``scheduler.step`` with its phases
+        ``scheduler.admit``/``prefill``/``decode`` as children
+        (``repro.serving.spans``)."""
+        with span("scheduler.step"):
+            return self._step(key)
+
+    def _step(self, key: Optional[jax.Array]) -> dict:
         now = self.clock()
         wall = time.perf_counter()
         self.stats["steps"] += 1
@@ -514,8 +523,12 @@ class Scheduler:
             k_fill, k_dec = jax.random.split(key)
         admitted = self._admit(now, wall)
         n_before = len(self.finished)
-        spent = self._spend_prefill_budget(now, k_fill)
-        decoded = self._decode(now, k_dec) if self.running else {}
+        with span("scheduler.prefill"):
+            spent = self._spend_prefill_budget(now, k_fill)
+        decoded = {}
+        if self.running:
+            with span("scheduler.decode"):
+                decoded = self._decode(now, k_dec)
         return {
             "admitted": [r.rid for r in admitted],
             "prefill_tokens": spent,
@@ -580,6 +593,8 @@ class Scheduler:
             # prefill work actually dispatched vs absorbed by the prefix
             # cache (saved = adopted tokens; both exact under StepClock)
             "prefill_tokens_dispatched": self.engine.stats["prefill_tokens"],
+            # rows those dispatches ran (every lane of each chunk)
+            "prefill_rows_dispatched": self.engine.stats["prefill_rows"],
             "prefill_tokens_saved": self.engine.stats["prefix_hit_tokens"],
             "recompute_tokens_saved": self.stats["recompute_tokens_saved"],
             "admission_reorders": self.stats["admission_reorders"],

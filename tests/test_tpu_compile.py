@@ -49,3 +49,23 @@ def test_pallas_grmac_compiles_for_v5e(one_chip, granularity, n_r):
         compiled = fn.lower(x, w).compile()
         assert "tpu_custom_call" in compiled.as_text(), (m, k, n)
         assert compiled.memory_analysis().output_size_in_bytes == m * n * 4
+
+
+@pytest.mark.parametrize("site", ["head", "attn_qkv"])
+def test_pallas_launch_is_named_after_its_site(one_chip, site):
+    """On the TPU an op's name is its HLO instruction's, which a launch
+    takes from the innermost jitted function around its pallas_call: each
+    model call site launches as ``%grmac_<site>``, and so the device trace
+    tells the sites apart."""
+    from repro.core.cim_config import CIMConfig
+    from repro.kernels.ops import cim_matmul
+    cim = CIMConfig(mode="grmac", granularity="row", fmt_x=FP6_E3M2,
+                    fmt_w=FP4_E2M1, n_r=32, backend="pallas")
+    x = jax.ShapeDtypeStruct((8, _D), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((_D, 256), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda x, w: cim_matmul(x, w, cim, site=site)).lower(
+        x, w).compile().as_text()
+    launches = [ln.strip() for ln in text.split("\n")
+                if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(launches) == 1
+    assert launches[0].startswith(f"%grmac_{site}")
