@@ -16,8 +16,8 @@ Two shapes by default, both recorded to experiments/bench/kernel_bench.json:
 
 * ``edge_decode`` (16×768×3072, paper-cim-120m FFN) — the paper's
   deployment regime: small-M matmuls are where the CIM path actually runs
-  per decoded token, and where the Pallas path's mandatory 128-alignment
-  padding wastes the most work.
+  per decoded token; the Pallas path runs them in 8-row blocks
+  (``dispatch.pallas_blocks``).
 * ``train_large_m`` (2048×768×3072) — the training-shape regime the
   ROADMAP flagged: the blocked einsum is bandwidth-bound there, and the
   fused ``tiled`` backend is the fix (the headline

@@ -104,7 +104,9 @@ def kernel_phase(seed: int) -> None:
             ref = np.asarray(oracle(x, w))
             diff = np.abs(out - ref)
             n_bad = int(np.count_nonzero(diff))
+            bm, bn, bk = dispatch.pallas_blocks(m, n, cim.n_r)
             log(f"[kernel] {gran:4s} m={m:3d} k={k} n={n}: plan=pallas "
+                f"blocks={bm}x{bn}x{bk} "
                 f"tpu_custom_call=yes compile_wall_s={t_compile:.3f} "
                 f"mismatches={n_bad}/{diff.size} "
                 f"max_abs_diff={float(diff.max()):.6g} "

@@ -55,9 +55,11 @@ to a ``Plan(backend, tile_m, tile_n)``:
 Padding: every backend requires ``K % n_r == 0`` (an analog column always
 has ``n_r`` physical rows; zero-padded entries still contribute their
 minimum-capacitance gain to the block denominator, exactly like unused
-hardware rows). The Pallas backends additionally need 128-aligned M/N/K
-tiles. ``grmac_matmul`` pads with zeros and slices the result, so all
-families see the *same* padded K blocks and agree numerically.
+hardware rows). The Pallas backends additionally need M, N and K padded to
+the blocks that ``pallas_blocks`` plans from the call's shape (M to 8 rows
+up to a 128-row block, N to 128 columns, K to ``lcm(128, n_r)``).
+``grmac_matmul`` pads with zeros and slices the result, so all families see
+the *same* padded K blocks and agree numerically.
 """
 from __future__ import annotations
 
@@ -82,6 +84,7 @@ __all__ = [
     "BACKENDS",
     "PLAN_CACHE_VERSION",
     "Plan",
+    "pallas_blocks",
     "resolve_backend",
     "plan_for",
     "plan_cache_path",
@@ -116,14 +119,23 @@ PLAN_CACHE_VERSION = 1
 # einsum wins; from M=64 the fused tiles win at every granularity.
 _TILED_MIN_M = 64
 
+# VMEM caps of the Pallas launch's blocks (``pallas_blocks``), from a
+# block_n sweep on one TPU v5e (PERF.md): the double-buffered f32 weight
+# tile (block_n <= 4096 at block_k 128; wider tiles ran no faster), and the
+# (block_m, block_n) f32 temporaries of the kernel body (128 x 8192 ran out
+# of the default scoped VMEM, 128 x 4096 fits).
+_PALLAS_W_TILE_BYTES = 4 << 20
+_PALLAS_ACC_ELEMS = 128 * 4096
+
 
 @dataclasses.dataclass(frozen=True)
 class Plan:
     """A dispatch decision: which backend runs a shape, with which tiles.
 
     ``tile_m``/``tile_n`` are only meaningful for ``tiled`` (0 means the
-    backend default / no N-tiling) and, rounded up to 128, for ``pallas``
-    block sizes.
+    backend default / no N-tiling) and for ``pallas``, where a nonzero
+    value pins the block (rounded up to 8 rows and 128 columns) and 0
+    leaves it to ``pallas_blocks``.
     """
     backend: str
     tile_m: int = 0
@@ -244,14 +256,43 @@ def _autotune_candidates(m, k, n, n_r):
     return cands
 
 
+def _round_up(v: int, mult: int) -> int:
+    return -(-v // mult) * mult
+
+
+def pallas_blocks(m: int, n: int, n_r: int, tile_m: int = 0,
+                  tile_n: int = 0) -> tuple[int, int, int]:
+    """``(block_m, block_n, block_k)`` of the Pallas launch for an
+    ``(m, K) @ (K, n)`` call.
+
+    ``block_m`` covers M up to one 128-row block, rounded up to the f32
+    sublane tile of 8: a decode call's handful of rows is not padded to
+    128. ``block_n`` is the widest multiple of 128 that divides N rounded up
+    to 128 (so no weight is padded beyond that) whose f32 weight tile,
+    double-buffered, fits ``_PALLAS_W_TILE_BYTES`` and whose
+    ``block_m × block_n`` f32 temporaries fit ``_PALLAS_ACC_ELEMS``.
+    ``block_k = lcm(128, n_r)``: whole analog columns on 128-lane tiles.
+    Explicit ``tile_m``/``tile_n`` pin the blocks (rounded up to 8 and 128).
+    Block shapes change no output bit: each output element's arithmetic
+    depends only on its row, its column and the K order.
+    """
+    bk = math.lcm(128, n_r)
+    bm = _round_up(tile_m, 8) if tile_m else min(128, _round_up(m, 8))
+    if tile_n:
+        return bm, _round_up(tile_n, 128), bk
+    cols = _round_up(n, 128) // 128
+    cap = max(1, min(_PALLAS_W_TILE_BYTES // (2 * 4 * bk * 128),
+                     _PALLAS_ACC_ELEMS // (bm * 128)))
+    width = max(d for d in range(1, min(cols, cap) + 1) if cols % d == 0)
+    return bm, 128 * width, bk
+
+
 def _run_plan(x, wq, plan: Plan, kwargs, tag: str = "") -> jax.Array:
     b = plan.backend
     if b in ("pallas", "pallas_interpret"):
-        n_r = kwargs["n_r"]
-        bm = max(128, -(-plan.tile_m // 128) * 128) if plan.tile_m else 128
-        bn = max(128, -(-plan.tile_n // 128) * 128) if plan.tile_n else 128
-        bk = math.lcm(128, n_r)
         m, n = x.shape[0], wq.shape[1]
+        bm, bn, bk = pallas_blocks(m, n, kwargs["n_r"], plan.tile_m,
+                                   plan.tile_n)
         xp = pad_to_multiple(pad_to_multiple(x, 0, bm), 1, bk)
         wp = pad_to_multiple(pad_to_multiple(wq, 0, bk), 1, bn)
         # a named call site launches under its own name (``%grmac_<site>``

@@ -18,16 +18,17 @@ outputs into the float32 output tile.
 The values matmul runs in bfloat16: both operands live on low-bit format
 grids (≤ 5 significant bits), so bf16 products/MXU accumulation are exact.
 
-Shapes must be pre-padded to multiples of the block sizes (see
-``dispatch._run_plan``, which also threads the planner's ``tile_m``/
-``tile_n`` — rounded up to 128 — into ``block_m``/``block_n``, so the TPU
-grid tiles M the same way the host-side tiled backend does); ``block_k``
-must be a multiple of ``n_r`` and 128-aligned for the MXU. The per-column
-epilogue (den -> ADC -> renorm -> accumulate) is fused in the kernel body,
-matching kernels/tiled.py's formulation. The ``block_k / n_r`` columns of a
-K-tile are unrolled with static slices: Mosaic has no lowering for a slice
-at a traced offset, and dispatch sizes ``block_k = lcm(128, n_r)``, so the
-unrolled body stays small.
+Shapes must be pre-padded to multiples of the block sizes, which
+``dispatch.pallas_blocks`` plans from the call's shape: ``block_m`` is M
+rounded up to 8 rows, up to 128, so a decode call's few rows run in an
+8-row block, and ``block_n`` is as wide as the VMEM caps allow while it
+divides N rounded up to 128; explicit ``tile_m``/``tile_n`` pin them.
+``block_k`` must be a multiple of ``n_r`` and 128-aligned for the MXU. The
+per-column epilogue (den -> ADC -> renorm -> accumulate) is fused in the
+kernel body, matching kernels/tiled.py's formulation. The ``block_k / n_r``
+columns of a K-tile are unrolled with static slices: Mosaic has no lowering
+for a slice at a traced offset, and dispatch sizes ``block_k = lcm(128,
+n_r)``, so the unrolled body stays small.
 
 The kernel only ever compiles for the TPU. Off-TPU, pass
 ``interpret=True`` (the ``pallas_interpret`` dispatch backend) to run it

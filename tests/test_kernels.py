@@ -2,6 +2,8 @@
 the jnp oracle (exact), Pallas-interpret vs oracle (slow debug cross-check),
 plan-based dispatch (heuristic + autotune cache), and the model-facing
 cim_matmul op."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ from repro.kernels.dispatch import (
     Plan,
     clear_plan_cache,
     grmac_matmul,
+    pallas_blocks,
     plan_for,
     resolve_backend,
 )
@@ -293,7 +296,44 @@ def test_pallas_never_falls_back_to_the_interpreter():
                             enob=8.0, granularity="row")
 
 
+# -------------------------------------------------- Pallas block planning
+@pytest.mark.parametrize("m", [8, 64, 1024])
+@pytest.mark.parametrize("k,n", [(1536, 2048), (1536, 1536), (1536, 8960),
+                                 (8960, 1536), (1536, 152064)])
+def test_pallas_blocks_from_call_shape(m, k, n):
+    """qwen2-1.5b's projection and head widths at decode, bucket-8 and
+    bucket-128 prefill rows: rows rounded up to 8 up to a 128-row block,
+    N blocks that tile N exactly (no weight padded), block_k unchanged;
+    explicit tiles still pin the blocks."""
+    bm, bn, bk = pallas_blocks(m, n, 32)
+    assert bm == min(128, -(-m // 8) * 8)
+    assert bk == math.lcm(128, 32)
+    # these widths are multiples of 128, so no weight is padded
+    assert bn % 128 == 0 and n % bn == 0 and k % bk == 0
+    assert pallas_blocks(m, n, 32, tile_m=128, tile_n=128) == (128, 128, 128)
+    assert pallas_blocks(m, n, 32, tile_m=20, tile_n=300) == (24, 384, 128)
+
+
 # ----------------------------------------- Pallas interpret-mode cross-check
+@pytest.mark.parametrize("granularity", ["conv", "row", "unit"])
+@pytest.mark.parametrize("m", [8, 64])
+def test_planned_pallas_blocks_match_128_blocks_bit_for_bit(granularity, m):
+    """The planned blocks (m rows, wide N) give the same bits as the kernel
+    at 128 x 128 x 128 blocks on the same operands: block shapes reorder no
+    output element's arithmetic."""
+    k, n = 256, 768
+    x, w = _data(jax.random.PRNGKey(7), m, k, n)
+    kw = dict(fmt_x=FP6_E3M2, fmt_w=FP4_E2M1, n_r=32, enob=8.0,
+              granularity=granularity)
+    bm, bn, _ = pallas_blocks(m, n, 32)
+    assert bm == m and bn > 128
+    planned = grmac_matmul(x, w, backend="pallas_interpret", **kw)
+    fixed = grmac_matmul_pallas(
+        jnp.pad(x, ((0, 128 - m), (0, 0))), w, block_m=128, block_n=128,
+        block_k=128, interpret=True, **kw)[:m]
+    assert jnp.array_equal(planned, fixed)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("granularity", ["conv", "row", "unit"])
 @pytest.mark.parametrize(

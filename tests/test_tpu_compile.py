@@ -42,13 +42,33 @@ def test_pallas_grmac_compiles_for_v5e(one_chip, granularity, n_r):
     kw = dict(fmt_x=FP6_E3M2, fmt_w=FP4_E2M1, n_r=n_r, enob=8.0,
               granularity=granularity, backend="pallas")
     fn = jax.jit(lambda x, w: grmac_matmul(x, w, **kw))
-    # decode rows, unaligned to the 128-row block: dispatch pads them
+    # decode rows, unaligned to the 8-row block: dispatch pads them
     for m, k, n in ((4, _D, _FF), (4, _FF, _D)):
         x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
         w = jax.ShapeDtypeStruct((k, n), jnp.float32, sharding=one_chip)
         compiled = fn.lower(x, w).compile()
         assert "tpu_custom_call" in compiled.as_text(), (m, k, n)
         assert compiled.memory_analysis().output_size_in_bytes == m * n * 4
+
+
+@pytest.mark.parametrize("granularity", ["conv", "row", "unit"])
+@pytest.mark.parametrize("m,k,n", [(8, _D, _ARCH.padded_vocab), (8, _FF, _D),
+                                   (1024, _D, _FF),
+                                   (1024, _D, _ARCH.padded_vocab)])
+def test_pallas_planned_blocks_compile_for_v5e(one_chip, granularity, m, k,
+                                               n):
+    """Decode rows at the head and the MLP's down projection, and a
+    bucket-128 prefill chunk's rows at the MLP and at the head (the widest
+    planned block, 128 x 3456), under the blocks ``pallas_blocks`` plans
+    for them: they fit the default scoped VMEM."""
+    kw = dict(fmt_x=FP6_E3M2, fmt_w=FP4_E2M1, n_r=32, enob=8.0,
+              granularity=granularity, backend="pallas")
+    x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((k, n), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda x, w: grmac_matmul(x, w, **kw)).lower(
+        x, w).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes == m * n * 4
 
 
 @pytest.mark.parametrize("site", ["head", "attn_qkv"])
