@@ -91,6 +91,7 @@ def control_readings(ctx) -> dict:
         w, [(tr.req.prompt, tr.req.generated) for tr in ctx.tracks])
     replay.attach_final_state(w, ctx.program_state)
     model = load_model(ctx.spec, params)
+    del params
     prog = replay.as_choices(w.served, b)
     vocab = ctx.spec["arch"]["vocab_size"]
     altered = {i: (ids + 1) % vocab for i, ids in prog.items()}

@@ -11,15 +11,24 @@ Conventions (the yardstick; a faster program moves the reading, not these):
   bytes over the HBM bandwidth; its roofline share is that time over the
   measured time, so no implementation can read above 100%.
 * Model FLOPs of a token: twice the weights of every projection and of
-  the head, plus attention's score and value products against the keys the
-  token sees. Work that a program repeats or pads does not count. Shares of the bf16 peak
-  use these.
+  the head, plus what the configuration's reference module counts beyond
+  them (``mixer_flops``: attention's score and value products against the
+  keys the token sees, a state space's update and readout). Work that a
+  program repeats or pads does not count. Shares of the bf16 peak use
+  these.
+
+Everything that depends on the architecture (the projections' weights and
+widths, the mixer's FLOPs) comes from the module that the configuration
+names under "reference" (``chipbench.reference``); what is here holds for
+every GR-MAC call.
 """
 from __future__ import annotations
 
 import json
 import re
 from pathlib import Path
+
+from chipbench import reference
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -53,41 +62,26 @@ def grmac_roofline_s(m: int, k: int, n: int, spec: dict, pk: dict) -> float:
                grmac_bytes(m, k, n, spec) / pk["hbm_bytes_per_s"])
 
 
-def projection_weights(arch: dict) -> int:
+def projection_weights(spec: dict) -> int:
     """Weights of every projection and the head (one token's matmuls)."""
-    d, v, n_l = arch["d_model"], arch["vocab_size"], arch["n_layers"]
-    _dense_only(arch)
-    h, kv, dh, f = (arch["n_heads"], arch["n_kv_heads"], arch["d_head"],
-                    arch["d_ff"])
-    layer = d * (h + 2 * kv) * dh + h * dh * d + 3 * d * f
-    return n_l * layer + d * v
+    return reference.module(spec).projection_weights(spec["arch"])
 
 
-def token_flops(arch: dict, position: int) -> float:
+def token_flops(spec: dict, position: int) -> float:
     """Model FLOPs of one token at ``position`` (0-based) of its sequence,
     forward only."""
-    return (2.0 * projection_weights(arch) + 4.0 * arch["n_layers"]
-            * arch["n_heads"] * arch["d_head"] * (position + 1))
+    return span_flops(spec, position, 1)
 
 
-def span_flops(arch: dict, start: int, count: int) -> float:
+def span_flops(spec: dict, start: int, count: int) -> float:
     """Model FLOPs of ``count`` tokens at positions start .. start+count-1."""
     if count <= 0:
         return 0.0
-    per_pos = 4.0 * arch["n_layers"] * arch["n_heads"] * arch["d_head"]
-    return (token_flops(arch, 0) * count
-            + per_pos * (count * start + count * (count - 1) / 2))
+    mod = reference.module(spec)
+    return (2.0 * mod.projection_weights(spec["arch"]) * count
+            + mod.mixer_flops(spec["arch"], start, count))
 
 
-def call_widths(arch: dict) -> list:
+def call_widths(spec: dict) -> list:
     """Output widths of the configuration's projections and head."""
-    _dense_only(arch)
-    d, v = arch["d_model"], arch["vocab_size"]
-    return [arch["n_heads"] * arch["d_head"],
-            arch["n_kv_heads"] * arch["d_head"], d, arch["d_ff"], v]
-
-
-def _dense_only(arch: dict) -> None:
-    if tuple(arch["block_pattern"]) != ("attn",):
-        raise ValueError(f"counts only dense attention layers, not "
-                         f"{arch['block_pattern']}")
+    return reference.module(spec).call_widths(spec["arch"])

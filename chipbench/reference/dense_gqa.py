@@ -20,6 +20,11 @@ at a lane's positions below ``given`` are read from the incoming cache, not
 from the step's own writes (but for the write of a lane that a decode
 step leaves idle): a replay passes the served program's state there, so
 that each position is computed from the same context as the program's.
+
+It also gives what the harness knows of this architecture (see
+``chipbench.reference``): the parameter tree, the projections' weights and
+widths, attention's FLOPs, and the kind of its cache (keys and values,
+written once a position).
 """
 from __future__ import annotations
 
@@ -32,6 +37,61 @@ from chipbench.reference.grmac import (
     HIGHEST, Numerics, grmac, parse_grid, quantize_weight)
 
 _NEG = -1e30
+STATE_KINDS = {"attn": "positional"}
+GROUP = "b0_attn"
+
+
+def _dense_only(a: dict) -> None:
+    if tuple(a["block_pattern"]) != ("attn",):
+        raise ValueError(f"dense_gqa covers dense attention layers only, "
+                         f"not {a['block_pattern']}")
+
+
+def param_specs(a: dict):
+    """One layer of the program's tree (the pattern is one "attn" layer,
+    so there is no tail), each leaf's init spec (``weights._leaf``)."""
+    _dense_only(a)
+    d, h, kv, dh, f = (a["d_model"], a["n_heads"], a["n_kv_heads"],
+                       a["d_head"], a["d_ff"])
+    out_scale = 1.0 / math.sqrt(2 * a["n_layers"])
+    attn = {"wq": {"w": (d, h * dh, 1 / math.sqrt(d))},
+            "wk": {"w": (d, kv * dh, 1 / math.sqrt(d))},
+            "wv": {"w": (d, kv * dh, 1 / math.sqrt(d))},
+            "wo": {"w": (h * dh, d, out_scale / math.sqrt(h * dh))}}
+    if a["qkv_bias"]:
+        for k, n in (("wq", h * dh), ("wk", kv * dh), ("wv", kv * dh)):
+            attn[k]["b"] = (n, "bias")
+    return {GROUP: {
+        "norm1": {"g": (d, "gain")},
+        "attn": attn,
+        "norm2": {"g": (d, "gain")},
+        "ffn": {"wi": {"w": (d, f, 1 / math.sqrt(d))},
+                "wg": {"w": (d, f, 1 / math.sqrt(d))},
+                "wo": {"w": (f, d, out_scale / math.sqrt(f))}}}}, {}
+
+
+def projection_weights(a: dict) -> int:
+    """Weights of every projection and the head (one token's matmuls)."""
+    _dense_only(a)
+    d, v, n_l = a["d_model"], a["vocab_size"], a["n_layers"]
+    h, kv, dh, f = a["n_heads"], a["n_kv_heads"], a["d_head"], a["d_ff"]
+    layer = d * (h + 2 * kv) * dh + h * dh * d + 3 * d * f
+    return n_l * layer + d * v
+
+
+def mixer_flops(a: dict, start: int, count: int) -> float:
+    """Attention's score and value products: a token at position p sees
+    p + 1 keys, 2 x 2 FLOPs a key and head dimension in every layer."""
+    per_pos = 4.0 * a["n_layers"] * a["n_heads"] * a["d_head"]
+    return per_pos * (count * start + count * (count + 1) / 2)
+
+
+def call_widths(a: dict) -> list:
+    """Output widths of the projections (q, k and v, o, the MLP's up and
+    down) and the head."""
+    _dense_only(a)
+    return [a["n_heads"] * a["d_head"], a["n_kv_heads"] * a["d_head"],
+            a["d_model"], a["d_ff"], a["vocab_size"]]
 
 
 def rmsnorm(x, g, eps=1e-6):
@@ -70,7 +130,7 @@ class Model:
 
     def _prepare(self, params):
         q = jax.vmap(lambda w: quantize_weight(w, self.gw))
-        blk = params["superblocks"]["b0_attn"]
+        blk = params["superblocks"][GROUP]
         at, ff = blk["attn"], blk["ffn"]
         layers = {"g1": blk["norm1"]["g"], "g2": blk["norm2"]["g"]}
         for name, p in (("wq", at["wq"]), ("wk", at["wk"]), ("wv", at["wv"]),
@@ -88,8 +148,8 @@ class Model:
     def init_cache(self, batch: int, ctx: int):
         a = self.spec["arch"]
         shape = (a["n_layers"], batch, ctx, a["n_kv_heads"], a["d_head"])
-        return {"k": jnp.zeros(shape, jnp.float32),
-                "v": jnp.zeros(shape, jnp.float32)}
+        return {GROUP: {"k": jnp.zeros(shape, jnp.float32),
+                        "v": jnp.zeros(shape, jnp.float32)}}
 
     def step(self, kind: str, numerics: Numerics, seq: int):
         key = (kind, numerics.name, seq)
@@ -115,7 +175,8 @@ class Model:
         act = numerics.act
         dense = self._dense
 
-        def fn(w, cache, toks, index, lane_arg, served, given):
+        def fn(w, state, toks, index, lane_arg, served, given):
+            cache = state[GROUP]
             b, s = toks.shape
             ctx = cache["k"].shape[2]
             slots = jnp.arange(ctx)[None, :]
@@ -177,9 +238,6 @@ class Model:
             arg = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             at = jnp.take_along_axis(logits[None], served[..., None],
                                      axis=-1)[..., 0]
-            return {"k": kn, "v": vn}, (top, arg, at)
+            return {GROUP: {"k": kn, "v": vn}}, (top, arg, at)
 
         return fn
-
-    def reset_lane(self, cache, lane: int):
-        return jax.tree.map(lambda c: c.at[:, lane].set(0.0), cache)

@@ -7,32 +7,51 @@ and what they chose, and each lane's state when it was handed on:
 
     ("begin",   slot, prompt)                      a lane starts a request
     ("end",     slot, state)                       the lane's state (every
-                                                   layer's K/V) just before
+                                                   layer group) just before
                                                    the next request resets it
     ("prefill", toks (B,S), index (B,), lens (B,), ids (B,S))
+    ("chunk",   state)                             after a prefill, the
+                                                   prefilling lane's
+                                                   recurrent groups
     ("decode",  toks (B,1), index (B,), active (B,), ids (B,))
 
 A lane's prefill that reaches its prompt's length serves the token chosen
-at its last row; a decode serves a token on every active lane.
+at its last row; a decode serves a token on every active lane. A state is
+a ``Snap``: one lane of a stack of states, read where it lies.
 
-Each dispatch is replayed from the program's own state: the keys and
-values every lane had written, as the program wrote them (taken from the
-lane's state when it was handed on, or after the window), including those
-the dispatch itself writes, which the reference reads in place of its own
-when it attends. So a rounding difference at one position does not spread
-to later ones, and every key and value the program wrote in the window is
-compared with what the reference computes from the same inputs and the
-same context. Only the residual stream of one position, from layer to
-layer, is the reference's own.
+Each layer group of the cache is replayed by the kind that the reference
+module gives it (``chipbench.reference``):
+
+* positional (keys and values): each dispatch is replayed from the
+  program's own state: the keys and values every lane had written, as the
+  program wrote them (taken from the lane's state when it was handed on,
+  or after the window), including those the dispatch itself writes, which
+  the reference reads in place of its own when it attends. So a rounding
+  difference at one position does not spread to later ones, and every key
+  and value the program wrote in the window is compared with what the
+  reference computes from the same inputs and the same context. Only the
+  residual stream of one position, from layer to layer, is the
+  reference's own.
+* recurrent (a state that each token overwrites): a lane starts its
+  request from zeros, as the program resets it. Each prefill chunk is
+  replayed from the lane's state as the program wrote it after the chunk
+  before, and the state the chunk writes is compared with the program's;
+  the lane then goes on from the program's. Decode steps carry the
+  reference's own state from the last chunk, and the state they reach is
+  compared with the program's when the lane is handed on (or after the
+  window). The other lanes of a dispatch ride on the reference's state.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench.reference import state_kinds
 
 
 def host_events(log: list) -> list:
@@ -40,11 +59,21 @@ def host_events(log: list) -> list:
     window); a lane's state stays on the device."""
     out = []
     for ev in log:
-        if ev[0] in ("begin", "end"):
+        if ev[0] in ("begin", "end", "chunk"):
             out.append(ev)
         else:
             out.append((ev[0],) + tuple(np.asarray(a) for a in ev[1:]))
     return out
+
+
+@dataclasses.dataclass(frozen=True)
+class Snap:
+    """One lane's state: index ``index`` of axis ``axis`` of every leaf of
+    ``tree`` ({group: {leaf: array}}), a store of lanes (axis 0) or a
+    cache (axis 1, the lane axis)."""
+    tree: dict
+    index: int
+    axis: int
 
 
 @dataclasses.dataclass
@@ -53,7 +82,7 @@ class Stint:
     slot: int
     prompt: List[int]
     served: List[int] = dataclasses.field(default_factory=list)
-    state: Optional[dict] = None        # its K/V when handed on
+    state: Optional[Snap] = None        # its state when handed on
     claimed: bool = False
 
 
@@ -69,6 +98,8 @@ class Walk:
     spans: Dict[int, Tuple[np.ndarray, np.ndarray]]
     # {begin event index: stint}
     begins: Dict[int, Stint]
+    # {prefill event index: the prefilling lane's recurrent state after it}
+    chunks: Dict[int, Snap] = dataclasses.field(default_factory=dict)
 
 
 def walk(events: list, batch: int) -> Walk:
@@ -83,8 +114,12 @@ def walk(events: list, batch: int) -> Walk:
     done = np.zeros(batch, bool)         # the prompt is all in
     last = np.zeros(batch, np.int64)     # the token served last
     stints: List[Stint] = []
-    served, spans, begins = {}, {}, {}
+    served, spans, begins, chunks = {}, {}, {}, {}
+    last_prefill = None
     for i, ev in enumerate(events):
+        if ev[0] == "chunk":
+            chunks[last_prefill] = ev[1]
+            continue
         if ev[0] == "begin":
             b = int(ev[1])
             st = Stint(b, list(ev[2]))
@@ -100,6 +135,7 @@ def walk(events: list, batch: int) -> Walk:
             continue
         before = fed.copy()
         if ev[0] == "prefill":
+            last_prefill = i
             toks, index, lens, ids = ev[1:5]
             lanes_out, ids_out = [], []
             for b in np.flatnonzero(lens > 0):
@@ -143,7 +179,7 @@ def walk(events: list, batch: int) -> Walk:
             if act.size:
                 served[i] = (act, ids[act])
         spans[i] = (before, fed.copy())
-    return Walk(faults, stints, served, spans, begins)
+    return Walk(faults, stints, served, spans, begins, chunks)
 
 
 def stream_faults(w: Walk, requests) -> List[str]:
@@ -168,16 +204,58 @@ def stream_faults(w: Walk, requests) -> List[str]:
 
 def attach_final_state(w: Walk, final: dict) -> None:
     """Give each stint still on its lane after the window that lane's
-    state: ``final`` is {name: (layers, B, ...)} on the device."""
+    state: ``final`` is {group: {leaf: (layers, B, ...)}} on the device."""
     for st in w.stints:
         if st.state is None:
-            st.state = {k: v[:, st.slot] for k, v in final.items()}
+            st.state = Snap(final, st.slot, 1)
+
+
+def _take(tree, index, axis):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, index, axis, False), tree)
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _set_lane(history, slot, tree, index, axis):
+    """``history`` with lane ``slot`` of every group set from a snapshot
+    (the snapshot's groups that ``history`` has)."""
+    state = _take({g: tree[g] for g in history}, index, axis)
+    return jax.tree.map(lambda h, s: h.at[:, slot].set(s.astype(h.dtype)),
+                        history, state)
+
+
+def set_lane(history: dict, slot: int, snap: Snap) -> dict:
+    if not history:
+        return history
+    return _set_lane(history, jnp.int32(slot), snap.tree,
+                     jnp.int32(snap.index), snap.axis)
 
 
 @jax.jit
-def _set_lane(history, slot, state):
-    return jax.tree.map(lambda h, s: h.at[:, slot].set(s.astype(h.dtype)),
-                        history, state)
+def _zero_lane(state, slot):
+    return jax.tree.map(lambda a: a.at[:, slot].set(0.0), state)
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _lane_error(state, slot, tree, index, axis):
+    """Per layer of each group, the squared distance of lane ``slot`` of
+    ``state`` from a snapshot's and the snapshot's squared size."""
+    other = _take({g: tree[g] for g in state}, index, axis)
+    out = {}
+    for g in state:
+        num = den = 0.0
+        for name in state[g]:
+            a = other[g][name].astype(jnp.float32)
+            d = state[g][name][:, slot].astype(jnp.float32) - a
+            num = num + jnp.sum(jnp.square(d).reshape(d.shape[0], -1), 1)
+            den = den + jnp.sum(jnp.square(a).reshape(a.shape[0], -1), 1)
+        out[g] = jnp.stack([num, den])
+    return out
+
+
+def lane_error(state: dict, slot: int, snap: Snap) -> dict:
+    return _lane_error(state, jnp.int32(slot), snap.tree,
+                       jnp.int32(snap.index), snap.axis)
 
 
 @jax.jit
@@ -211,9 +289,10 @@ def _written_error(new, against, lo, hi):
 @dataclasses.dataclass
 class Result:
     """Per served event, the reference's best logit, its argmax and the
-    logits of each choice; per layer, the relative distance of the keys
-    and values the program wrote from the reference's (and of each
-    ``other`` numerics' from the reference's)."""
+    logits of each choice; per layer (the cache's groups in order, each
+    group's layers), the relative distance of the state the program wrote
+    from the reference's (and of each ``other`` numerics' from the
+    reference's)."""
     tokens: Dict[int, dict]
     state_error: List[float]
     other_state_error: List[List[float]]
@@ -222,32 +301,66 @@ class Result:
 def run(model, w: Walk, events: list, numerics, choices: List[Dict[int,
         np.ndarray]], batch: int, ctx: int, others=()) -> Result:
     """Drive ``model`` through ``events`` in ``numerics``, each dispatch
-    from the program's state. ``choices`` are sets of token
-    choices ({event index: (B,) ids}) whose logits are read; ``others``
-    are further numerics run from the same context: their writes are
-    compared with the reference's, and their first choices are read after
-    ``choices`` (as choice ``len(choices) + j``)."""
-    history = model.init_cache(batch, ctx)
+    from the program's state (as the module docstring says, by the kind
+    of each layer group). ``choices`` are sets of token choices ({event
+    index: (B,) ids}) whose logits are read; ``others`` are further
+    numerics run from the same context: their writes are compared with
+    the reference's, and their first choices are read after ``choices``
+    (as choice ``len(choices) + j``)."""
+    kinds = state_kinds(model.spec)
+    init = model.init_cache(batch, ctx)
+    history = {g: init[g] for g in kinds if kinds[g] == "positional"}
+    rec = [g for g in kinds if kinds[g] == "recurrent"]
+    # the recurrent state each numerics carries (the reference's first)
+    lanes = [{g: init[g] for g in rec} for _ in range(1 + len(others))]
+    layers = {g: next(iter(init[g].values())).shape[0] for g in kinds}
+    del init
     zeros = np.zeros(batch, np.int32)
     pending = {}
-    n_l = next(iter(history.values())).shape[0]
-    acc = [jnp.zeros((2, n_l), jnp.float32) for _ in range(1 + len(others))]
+    acc = [{g: 0.0 for g in kinds} for _ in range(1 + len(others))]
+    stint: Dict[int, Stint] = {}      # the stint on each lane
+    ended = set()                     # stints handed on in the log
+    stale = np.zeros(batch, bool)     # decoded since the program's state
+
+    def compare(slot, snap):
+        """The lane's recurrent state against the program's, and each
+        other numerics' against the reference's."""
+        errs = lane_error(lanes[0], slot, snap)
+        for g in rec:
+            acc[0][g] = acc[0][g] + errs[g]
+        for j in range(1, len(lanes)):
+            errs = lane_error(lanes[j], slot, Snap(lanes[0], slot, 1))
+            for g in rec:
+                acc[j][g] = acc[j][g] + errs[g]
+
     for i, ev in enumerate(events):
         if ev[0] == "begin":
-            history = _set_lane(history, jnp.int32(ev[1]),
-                                w.begins[i].state)
+            slot = int(ev[1])
+            history = set_lane(history, slot, w.begins[i].state)
+            if rec:
+                lanes = [_zero_lane(st, jnp.int32(slot)) for st in lanes]
+            stint[slot] = w.begins[i]
+            stale[slot] = False
             continue
         if ev[0] == "end":
+            slot = int(ev[1])
+            if rec and stale[slot]:
+                compare(slot, ev[2])
+            if slot in stint:
+                ended.add(id(stint[slot]))
+            continue
+        if ev[0] == "chunk":
             continue
         kind, toks, index, lane_arg = ev[0], ev[1], ev[2], ev[3]
         lo, hi = (jnp.asarray(a, jnp.int32) for a in w.spans[i])
         args = (jnp.asarray(toks, jnp.int32), jnp.asarray(index, jnp.int32),
                 jnp.asarray(lane_arg))
         outs, first = [], []
-        for other in others:
+        for j, other in enumerate(others):
             fn = model.step(kind, other, toks.shape[1])
-            new, (_, arg, _) = fn(model.weights, _context(history, hi),
-                                  *args, jnp.zeros((1, batch), jnp.int32), hi)
+            new, (_, arg, _) = fn(model.weights, {
+                **_context(history, hi), **lanes[1 + j]}, *args,
+                jnp.zeros((1, batch), jnp.int32), hi)
             outs.append(new)
             first.append(arg)
         served = jnp.asarray(np.stack([c.get(i, zeros) for c in choices])
@@ -255,22 +368,43 @@ def run(model, w: Walk, events: list, numerics, choices: List[Dict[int,
         if first:
             served = jnp.concatenate([served, jnp.stack(first)])
         fn = model.step(kind, numerics, toks.shape[1])
-        new, res = fn(model.weights, _context(history, hi), *args, served,
-                      hi)
-        num, den = _written_error(new, history, lo, hi)
-        acc[0] = acc[0] + jnp.stack([num, den])
-        for j, o in enumerate(outs):
-            num, den = _written_error(o, new, lo, hi)
-            acc[1 + j] = acc[1 + j] + jnp.stack([num, den])
+        new, res = fn(model.weights, {**_context(history, hi), **lanes[0]},
+                      *args, served, hi)
+        for g in history:
+            num, den = _written_error(new[g], history[g], lo, hi)
+            acc[0][g] = acc[0][g] + jnp.stack([num, den])
+            for j, o in enumerate(outs):
+                num, den = _written_error(o[g], new[g], lo, hi)
+                acc[1 + j][g] = acc[1 + j][g] + jnp.stack([num, den])
+        if rec:
+            lanes = [{g: n[g] for g in rec} for n in [new] + outs]
+            if kind == "decode":
+                stale |= np.asarray(lane_arg, bool)
+            else:
+                fed = np.flatnonzero(np.asarray(lane_arg) > 0)
+                if fed.size != 1 or i not in w.chunks:
+                    raise ValueError(f"event {i}: a prefill of lanes "
+                                     f"{fed.tolist()} without one kept "
+                                     f"state")
+                slot, snap = int(fed[0]), w.chunks[i]
+                compare(slot, snap)
+                # on from the program's state
+                lanes = [set_lane(st, slot, snap) for st in lanes]
+                stale[slot] = False
         if i in w.served:
             pending[i] = res
+    for slot, st in stint.items():
+        if rec and id(st) not in ended and stale[slot]:
+            compare(slot, st.state)
     tokens = {i: {"top": t, "arg": a, "at": at}
               for i, (t, a, at) in jax.device_get(pending).items()}
-    errs = [_relative(a) for a in jax.device_get(acc)]
+    # a group that nothing in the log wrote reads 0
+    errs = [sum((_relative(a[g]) if np.ndim(a[g]) else [0.0] * layers[g]
+                 for g in kinds), []) for a in jax.device_get(acc)]
     return Result(tokens, errs[0], errs[1:])
 
 
-def _relative(acc: np.ndarray) -> List[float]:
+def _relative(acc) -> List[float]:
     num, den = np.asarray(acc, np.float64)
     return [float(np.sqrt(n / d)) if d > 0 else 0.0 for n, d in zip(num, den)]
 

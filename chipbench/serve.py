@@ -37,32 +37,55 @@ class DispatchLog:
     """Records what the engine's compiled programs are given and choose,
     through the engine's per-instance dispatch seams, without a transfer:
     the arrays stay on the device until the window has closed. Before a
-    lane is reset for its next request it also copies the lane's state
-    into a store made at set-up (``resets`` lanes deep, written in place,
-    so the window allocates nothing), so that the check can replay each
-    dispatch from the state the program had."""
+    lane is reset for its next request it also copies the lane's state, in
+    every layer group of the cache, into a store made at set-up
+    (``resets`` lanes deep, written in place, so the window allocates
+    nothing), so that the check can replay each dispatch from the state
+    the program had. A recurrent group (``kinds``, from the reference
+    module) is overwritten by every token, so after each prefill chunk the
+    prefilling lane's recurrent state is also copied, into a store
+    ``chunks`` deep."""
 
-    def __init__(self, engine, resets: int):
+    def __init__(self, engine, resets: int, chunks: int, kinds: dict):
         import jax
         import jax.numpy as jnp
         self.events = []
         prefill, decode, begin, reset = (
             engine._compiled_prefill, engine._compiled_decode,
             engine.begin_request, engine._reset_slot_state)
-        self.store = jax.tree.map(
-            lambda a: jnp.zeros((resets,) + a.shape[:1] + a.shape[2:],
-                                a.dtype), state_layers(engine.cache))
+        groups = state_groups(engine.cache)
+        recurrent = [g for g in groups if kinds[g] == "recurrent"]
+
+        def zeros(tree, depth):
+            return jax.tree.map(lambda a: jnp.zeros(
+                (depth,) + a.shape[:1] + a.shape[2:], a.dtype), tree)
+
         def keep_lane_state(store, cache, slot, j):
             # a named function: the trace tells programs apart by name, and
             # the engine's prefill programs are anonymous lambdas
             return jax.tree.map(lambda s, a: s.at[j].set(
                 jax.lax.dynamic_index_in_dim(a, slot, 1, False)),
-                store, state_layers(cache))
+                store, state_groups(cache))
+
+        def keep_chunk_state(store, cache, lens, j):
+            # the one lane that a prefill dispatch feeds, found on the device
+            slot = jnp.argmax(lens > 0)
+            now = state_groups(cache)
+            return jax.tree.map(lambda s, a: s.at[j].set(
+                jax.lax.dynamic_index_in_dim(a, slot, 1, False)),
+                store, {g: now[g] for g in store})
 
         put = jax.jit(keep_lane_state, donate_argnums=(0,))
-        self.store = put(self.store, engine.cache, 0, 0)   # compiled now
-        jax.block_until_ready(self.store)
-        kept = [0]
+        self.store = put(zeros(groups, resets), engine.cache, 0, 0)
+        self.chunks = None
+        if recurrent:
+            put_chunk = jax.jit(keep_chunk_state, donate_argnums=(0,))
+            self.chunks = put_chunk(
+                zeros({g: groups[g] for g in recurrent}, chunks),
+                engine.cache, jnp.zeros(engine.cfg.batch_slots, jnp.int32),
+                0)                                      # compiled now
+        jax.block_until_ready((self.store, self.chunks))
+        kept = [0, 0]
 
         def compiled_prefill(bucket):
             fn = prefill(bucket)
@@ -70,6 +93,14 @@ class DispatchLog:
             def call(params, toks, cache, index, lens):
                 out = fn(params, toks, cache, index, lens)
                 self.events.append(("prefill", toks, index, lens, out[1]))
+                if self.chunks is not None:
+                    j = kept[1]
+                    if j >= chunks:
+                        raise RuntimeError(f"more than {chunks} prefill "
+                                           f"chunks in the window")
+                    self.chunks = put_chunk(self.chunks, out[2], lens, j)
+                    self.events.append(("chunk", j))
+                    kept[1] = j + 1
                 return out
             return call
 
@@ -103,9 +134,26 @@ class DispatchLog:
         engine._reset_slot_state = reset_slot_state
 
     def logged(self) -> list:
-        """The events, each kept lane state taken out of the store."""
-        return [(ev[0], ev[1], {k: v[ev[2]] for k, v in self.store.items()})
-                if ev[0] == "end" else ev for ev in self.events]
+        """The events, each kept lane state as a ``replay.Snap`` of its
+        store (read in place, not copied)."""
+        from chipbench.reference.replay import Snap
+        out = []
+        for ev in self.events:
+            if ev[0] == "end":
+                ev = ("end", ev[1], Snap(self.store, ev[2], 0))
+            elif ev[0] == "chunk":
+                ev = ("chunk", Snap(self.chunks, ev[1], 0))
+            out.append(ev)
+        return out
+
+
+def chunk_bound(reqs, serve: dict) -> int:
+    """The most prefill chunks ``reqs`` can take: a prompt is cut at the
+    budget and the largest bucket, and once more where it starts in a step
+    whose budget another prompt has begun."""
+    step = min(serve["prefill_token_budget"],
+               serve.get("prefill_bucket_max", 1024))
+    return sum(-(-len(r.prompt) // step) + 1 for r in reqs)
 
 
 def buckets(serve: dict) -> List[int]:
@@ -239,9 +287,7 @@ def _stamp(live: List[Track]) -> None:
 
 def run(ctx) -> None:
     """One run of a serving cell; fills ``ctx`` with results."""
-    params, events = window(ctx)
-    ctx.log_events = events
-    _check(ctx, params, events)
+    _check(ctx, *window(ctx))
 
 
 def window(ctx):
@@ -252,12 +298,13 @@ def window(ctx):
     from repro.serving.engine import Engine
     from repro.serving.scheduler import Scheduler
 
-    from chipbench import weights
+    from chipbench import reference, weights
 
     spec, settings, mix = ctx.spec, ctx.settings, ctx.mix
     serve = settings["serve"]
     arch = harness.arch_from_spec(spec)
     vocab = spec["arch"]["vocab_size"]
+    kinds = reference.state_kinds(spec)
     params = weights.make_params(spec, ctx.seed)
     weights.check_layout(params, jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), arch)))
@@ -266,7 +313,8 @@ def window(ctx):
     scfg, qcfg = engine_configs(serve)
     engine = Engine(arch, params, scfg)
     # a lane is reset only as a request due in the window takes it
-    log = DispatchLog(engine, sum(r.due_s < ctx.seconds for r in reqs))
+    due = [r for r in reqs if r.due_s < ctx.seconds]
+    log = DispatchLog(engine, len(due), chunk_bound(due, serve), kinds)
     sched = Scheduler(engine, qcfg)
     jax.block_until_ready((params, engine.cache))
     compiles0 = ctx.compiles.n
@@ -285,15 +333,20 @@ def window(ctx):
     _serve_metrics(ctx, tracks, lateness)
 
     events = log.logged()
-    ctx.program_state = state_layers(engine.cache)
+    ctx.program_state = state_groups(engine.cache)
     del log, sched, engine
     gc.collect()
     return params, events
 
 
-def state_layers(cache) -> dict:
-    """The engine's per-layer state as {name: (layers, B, ...) array}."""
-    return dict(next(iter(cache["superblocks"].values())))
+def state_groups(cache) -> dict:
+    """Every layer group of the engine's cache as {group: {leaf: (layers,
+    B, ...)}}: each group of the super-blocks as stacked, and each tail
+    layer with a layer axis of its own."""
+    out = dict(cache.get("superblocks", {}))
+    for name, group in cache.get("tail", {}).items():
+        out[name] = {k: a[None] for k, a in group.items()}
+    return out
 
 
 def _serve_metrics(ctx, tracks, lateness) -> None:
@@ -342,6 +395,7 @@ def _check(ctx, params, events) -> None:
     from chipbench.reference.grmac import REFERENCE
 
     t = harness.now()
+    ctx.log_events = events
     serve = ctx.settings["serve"]
     b = serve["batch_slots"]
     host = replay.host_events(events)
@@ -350,6 +404,7 @@ def _check(ctx, params, events) -> None:
         w, [(tr.req.prompt, tr.req.generated) for tr in ctx.tracks])
     replay.attach_final_state(w, ctx.program_state)
     model = load_model(ctx.spec, params)
+    del params
     res = replay.run(model, w, host, REFERENCE[ctx.spec["arch"]["dtype"]],
                      [replay.as_choices(w.served, b)], b, serve["max_ctx"])
     g = replay.gaps(res, w.served)

@@ -165,7 +165,6 @@ class RunData:
         self.programs = programs
         self.events = events
         self.spec = spec
-        self.arch = spec["arch"]
         self.peaks = peaks
         self.chips = chips
         self.tracks = list(tracks)
@@ -198,7 +197,7 @@ class RunData:
         for e in self.events:
             if e[0] == "prefill":
                 for start, n in zip(e[2], e[3]):
-                    total += flops.span_flops(self.arch, int(start), int(n))
+                    total += flops.span_flops(self.spec, int(start), int(n))
         return total
 
     def decode_flops(self) -> float:
@@ -207,7 +206,7 @@ class RunData:
             if e[0] == "decode":
                 for pos, on in zip(e[2], e[3]):
                     if on:
-                        total += flops.token_flops(self.arch, int(pos))
+                        total += flops.token_flops(self.spec, int(pos))
         return total
 
     # --- kernels and the CIM layer --------------------------------------
@@ -221,7 +220,7 @@ class RunData:
                     if self._kind_of(m[0]) == k] for k in self.programs}
         rows = {k: [int(np.prod(e[1].shape)) for e in self.events
                     if e[0] == k] for k in self.programs}
-        widths = flops.call_widths(self.arch)
+        widths = flops.call_widths(self.spec)
         spans = []
         for k in runs:
             if len(runs[k]) != len(rows[k]):

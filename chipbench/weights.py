@@ -2,6 +2,13 @@
 one jitted call, in the dtypes and tree layout that the program serves
 (checked against a shape-only trace of the program's own initialiser).
 
+The layers' tree comes from the configuration's reference module
+(``param_specs``, see ``chipbench.reference``): each leaf is an init spec,
+``(rows, cols, std)`` for a normal matrix, ``(n, kind)`` for a vector of a
+kind drawn here (``gain`` around 1, ``bias`` around 0), or a function
+``(key, lead, dtype) -> array`` for a leaf only that architecture has.
+Embedding, final norm and an untied head are made here for every module.
+
 Scales follow the usual initialisation: input projections N(0, 1/d_in),
 output projections further divided by sqrt(2 * n_layers), embeddings
 N(0, 0.02^2). Norm gains and biases are drawn around their customary
@@ -13,6 +20,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import reference
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -26,29 +35,11 @@ def padded_vocab(v: int) -> int:
     return -(-v // 256) * 256
 
 
-def _dense_layer_shapes(a: dict) -> dict:
-    d, h, kv, dh, f = (a["d_model"], a["n_heads"], a["n_kv_heads"],
-                       a["d_head"], a["d_ff"])
-    out_scale = 1.0 / math.sqrt(2 * a["n_layers"])
-    attn = {"wq": {"w": (d, h * dh, 1 / math.sqrt(d))},
-            "wk": {"w": (d, kv * dh, 1 / math.sqrt(d))},
-            "wv": {"w": (d, kv * dh, 1 / math.sqrt(d))},
-            "wo": {"w": (h * dh, d, out_scale / math.sqrt(h * dh))}}
-    if a["qkv_bias"]:
-        for k, n in (("wq", h * dh), ("wk", kv * dh), ("wv", kv * dh)):
-            attn[k]["b"] = (n, "bias")
-    return {"b0_attn": {
-        "norm1": {"g": (d, "gain")},
-        "attn": attn,
-        "norm2": {"g": (d, "gain")},
-        "ffn": {"wi": {"w": (d, f, 1 / math.sqrt(d))},
-                "wg": {"w": (d, f, 1 / math.sqrt(d))},
-                "wo": {"w": (f, d, out_scale / math.sqrt(f))}}}}
-
-
 def _leaf(key, shape_spec, lead, dtype):
     """One array from its spec: (rows, cols, std) is a normal matrix;
-    (n, kind) a vector of the named kind."""
+    (n, kind) a vector of the named kind; a function draws its own."""
+    if callable(shape_spec):
+        return shape_spec(key, lead, dtype)
     if isinstance(shape_spec[-1], str):
         n, kind = shape_spec
         shape = lead + (n,)
@@ -63,26 +54,35 @@ def _leaf(key, shape_spec, lead, dtype):
                                     jnp.float32)).astype(dtype)
 
 
+def _is_spec(x) -> bool:
+    return isinstance(x, tuple) or callable(x)
+
+
 def make_params(spec: dict, seed: int):
     """The program's parameter tree for ``spec``, on the device."""
     a = spec["arch"]
     dtype = jnp.dtype(a["dtype"])
-    if tuple(a["block_pattern"]) != ("attn",):
-        raise NotImplementedError("dense attention layers only")
-    layer = _dense_layer_shapes(a)
-    leaves, treedef = jax.tree.flatten(
-        layer, is_leaf=lambda x: isinstance(x, tuple))
+    superblock, tail = reference.module(spec).param_specs(a)
+    sb_leaves, sb_def = jax.tree.flatten(superblock, is_leaf=_is_spec)
+    tl_leaves, tl_def = jax.tree.flatten(tail, is_leaf=_is_spec)
+    n_super = a["n_layers"] // len(a["block_pattern"])
     vp = padded_vocab(a["vocab_size"])
 
     def build(key):
-        ks = jax.random.split(key, len(leaves) + 3)
-        lead = (a["n_layers"],)
-        arrs = [_leaf(ks[i], s, lead, dtype) for i, s in enumerate(leaves)]
+        n = len(sb_leaves)
+        ks = jax.random.split(key, n + len(tl_leaves) + 3)
         p = {"embed": (0.02 * jax.random.normal(
                  ks[-3], (vp, a["d_model"]), jnp.float32)).astype(dtype),
-             "superblocks": jax.tree.unflatten(treedef, arrs),
              "final_norm": {"g": _leaf(ks[-2], (a["d_model"], "gain"), (),
                                        dtype)}}
+        if sb_leaves:
+            p["superblocks"] = jax.tree.unflatten(sb_def, [
+                _leaf(ks[i], s, (n_super,), dtype)
+                for i, s in enumerate(sb_leaves)])
+        if tl_leaves:
+            p["tail"] = jax.tree.unflatten(tl_def, [
+                _leaf(ks[n + i], s, (), dtype)
+                for i, s in enumerate(tl_leaves)])
         if not a["tie_embeddings"]:
             p["lm_head"] = {"w": _leaf(
                 ks[-1], (a["d_model"], vp, 1 / math.sqrt(a["d_model"])), (),

@@ -12,11 +12,11 @@ import pytest
 
 from chipbench import run as bench_run
 from chipbench import serve
-from tinycell import TINY_GAP_LIMIT, tiny_args, tiny_cell
+from tinycell import TINY_GAP_LIMIT, tiny_args, tiny_cell, tiny_ssm_cell
 
 
-def _run():
-    bench, cell = tiny_cell()
+def _run(make=tiny_cell):
+    bench, cell = make()
     result, ctx = bench_run.run(tiny_args(), require_tpu=False,
                                 bench=bench, cell=cell, compile_cache=False)
     return result, {c.name: c for c in ctx.checks}
@@ -45,9 +45,7 @@ def _broken_prefill(arch, bucket):
     return jax.jit(fn)
 
 
-@pytest.mark.parametrize("fault", [
-    "token_altered", "state_unchanged", "prefill_state_unchanged"])
-def test_broken_path_is_not_correct(fault, monkeypatch):
+def _break(fault, monkeypatch):
     from repro.serving import engine
     if fault == "prefill_state_unchanged":
         monkeypatch.setattr(engine, "_prefill_fn",
@@ -55,7 +53,26 @@ def test_broken_path_is_not_correct(fault, monkeypatch):
     else:
         monkeypatch.setattr(engine, "_decode_fn", functools.lru_cache()(
             functools.partial(_broken_decode, fault)))
+
+
+@pytest.mark.parametrize("fault", [
+    "token_altered", "state_unchanged", "prefill_state_unchanged"])
+def test_broken_path_is_not_correct(fault, monkeypatch):
+    _break(fault, monkeypatch)
     result, checks = _run()
+    assert not result["correct"]
+    assert (checks["max_logit_gap"].value > TINY_GAP_LIMIT
+            or checks["state_error_worst_layer"].value > TINY_GAP_LIMIT)
+
+
+@pytest.mark.parametrize("fault", [
+    "token_altered", "state_unchanged", "prefill_state_unchanged"])
+def test_ssm_broken_path_is_not_correct(fault, monkeypatch):
+    """The same faults under the mamba2 cell, whose state each token
+    overwrites: a decode step or a prefill chunk that keeps the state it
+    was given, and a token altered where it is produced."""
+    _break(fault, monkeypatch)
+    result, checks = _run(tiny_ssm_cell)
     assert not result["correct"]
     assert (checks["max_logit_gap"].value > TINY_GAP_LIMIT
             or checks["state_error_worst_layer"].value > TINY_GAP_LIMIT)

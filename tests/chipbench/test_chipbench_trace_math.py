@@ -96,13 +96,13 @@ def test_readers_on_the_known_trace():
              + flops.grmac_roofline_s(8, 1536, 151936, SPEC, PEAKS))
     assert serving.grmac_roofline_pct(run) == pytest.approx(
         100 * floor / 1200e-9)
-    dec = (flops.token_flops(SPEC["arch"], 100)
-           + flops.token_flops(SPEC["arch"], 5)
-           + flops.token_flops(SPEC["arch"], 101))
+    dec = (flops.token_flops(SPEC, 100)
+           + flops.token_flops(SPEC, 5)
+           + flops.token_flops(SPEC, 101))
     assert run.decode_flops() == pytest.approx(dec)
     assert serving.program_mfu_pct(run, "decode") == pytest.approx(
         100 * dec / (800e-9 * PEAKS["bf16_flops_per_s"]))
-    pre = flops.span_flops(SPEC["arch"], 0, 100)
+    pre = flops.span_flops(SPEC, 0, 100)
     assert run.prefill_flops() == pytest.approx(pre)
     assert serving.program_mfu_pct(run, "prefill") == pytest.approx(
         100 * pre / (1000e-9 * PEAKS["bf16_flops_per_s"]))

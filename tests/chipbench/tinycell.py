@@ -41,6 +41,28 @@ def tiny_cell(dtype: str = "float32") -> tuple:
     return bench, cell
 
 
+def mamba2_spec() -> dict:
+    """mamba2-1.3b at its published widths, in the benchmark's
+    configuration format. No cell runs it on the chip yet (PERF.md, Open
+    questions), so it lives beside the tests that prove its reference
+    module on the CPU, without limits of its own."""
+    return json.loads((ROOT / "tests" / "chipbench" / "mamba2-1.3b-grmac.json")
+                      .read_text())
+
+
+def tiny_ssm_cell(dtype: str = "float32") -> tuple:
+    """(bench, cell) for a run of a toy-width mamba2 cell: the dense
+    cell's traffic and settings with the mamba2 configuration."""
+    bench, cell = tiny_cell(dtype)
+    spec = mamba2_spec()
+    spec["check"] = dict(cell["spec"]["check"])
+    spec["arch"].update(n_layers=2, d_model=64, ssm_state=16,
+                        ssm_headdim=16, vocab_size=500, dtype=dtype)
+    cell["spec"] = spec
+    cell["entry"] = dict(cell["entry"], config=spec["name"])
+    return bench, cell
+
+
 def tiny_args(seed: int = 2 ** 33 + 5, seconds: float = 1.5):
     return argparse.Namespace(workload="tiny.dense", seed=seed,
                               seconds=seconds, trace=0)
@@ -48,13 +70,15 @@ def tiny_args(seed: int = 2 ** 33 + 5, seconds: float = 1.5):
 
 def main(argv) -> int:
     """Prints the control's readings of a tiny cell in ``argv[1]``'s
-    dtype, with XLA held to the stated precision."""
+    dtype (the mamba2 cell where ``argv[2]`` is "ssm"), with XLA held to
+    the stated precision."""
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     from chipbench import harness
     harness.strict_precision()
     from chipbench import control
     from chipbench import run as bench_run
-    bench, cell = tiny_cell(argv[1])
+    make = tiny_ssm_cell if argv[2:] == ["ssm"] else tiny_cell
+    bench, cell = make(argv[1])
     cell["spec"]["arch"]["n_layers"] = 4
     ctx = bench_run.Context(tiny_args(), cell, bench)
     r = control.control_readings(ctx)
